@@ -425,14 +425,7 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action="store_true",
                     help="fast identity + regression gate (tier-1)")
-    ap.add_argument("--compile-cache", default=None, metavar="DIR",
-                    help="enable jax's persistent compilation cache in "
-                         "DIR: jit'd kernels compile once per machine "
-                         "instead of once per process (opt-in)")
     args = ap.parse_args()
-    if args.compile_cache:
-        from repro.core.decode import enable_persistent_compilation_cache
-        enable_persistent_compilation_cache(args.compile_cache)
     if args.smoke:
         smoke()
     else:

@@ -7,36 +7,21 @@
 # or on a streamed-vs-serial perf regression — so `make verify` / CI
 # stop on benchmark-smoke regressions instead of just printing them.
 # `hypothesis` is optional — when absent, tests/conftest.py swaps in the
-# vendored deterministic stub.
+# vendored deterministic stub. Everything here runs on the CPU, with the
+# Pallas kernels in interpret mode; `python chip_smoke.py` is the check
+# on a TPU. A jax compile cache is used only where
+# JAX_COMPILATION_CACHE_DIR is set, or where an entry point places it
+# (core.decode.enable_persistent_compilation_cache).
 #
 #   scripts/test.sh              # whole suite (-x -q) + smoke gates
 #   scripts/test.sh tests/test_cache.py -k lru   # any pytest args
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
-# Persistent jax compilation cache for the BENCHMARK SMOKE processes:
-# the Pallas/jit decode kernels cost ~4-7s per lane bucket on first
-# compile, and without a cache every smoke process in this script pays
-# it again. A tmpdir cache survives across runs on the same machine and
-# is harmless to delete. Honor a caller-provided JAX_COMPILATION_CACHE_DIR.
-#
-# Deliberately NOT exported to the pytest process: on jax 0.4.37 CPU an
-# executable reloaded from the persistent cache is not bit-identical to
-# a freshly compiled one for float programs (different fusion decisions
-# survive serialization), which breaks the bitwise-resume determinism
-# test in test_checkpoint_elastic.py. The decode smokes are safe — their
-# kernels are pure integer ops and every number is byte-identity-gated
-# against the serial oracle anyway.
-: "${JAX_COMPILATION_CACHE_DIR:=${TMPDIR:-/tmp}/repro-jax-cache}"
-JAX_CACHE_ENV=(
-    "JAX_COMPILATION_CACHE_DIR=$JAX_COMPILATION_CACHE_DIR"
-    "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS=0"
-    "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES=-1"
-)
+export JAX_PLATFORMS=cpu
 if [ "$#" -eq 0 ]; then
     python -m pytest -x -q tests
-    if ! env "${JAX_CACHE_ENV[@]}" \
-        PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
+    if ! env PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
         python benchmarks/e2e_read_latency.py --smoke; then
         echo "FAIL: benchmark smoke regression (see SMOKE REGRESSION above)" >&2
         exit 1
@@ -44,8 +29,7 @@ if [ "$#" -eq 0 ]; then
     # decode-kernel gate: every registered backend byte-identical to the
     # serial oracle and holding at least half its recorded throughput
     # ratio vs the same-run serial oracle (see decode_kernels.py)
-    if ! env "${JAX_CACHE_ENV[@]}" \
-        PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
+    if ! env PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
         python benchmarks/decode_kernels.py --smoke; then
         echo "FAIL: decode kernel smoke regression (see above)" >&2
         exit 1
@@ -53,8 +37,7 @@ if [ "$#" -eq 0 ]; then
     # fault-injection gate: a stripe node crashed/blackholed MID-streamed-
     # restore must not change restored bytes, and one crashed node must
     # not drop the L2 hit rate below the healthy-run ratio
-    if ! env "${JAX_CACHE_ENV[@]}" \
-        PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
+    if ! env PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
         python benchmarks/fault_injection.py --smoke; then
         echo "FAIL: fault-injection smoke regression (see above)" >&2
         exit 1
@@ -65,8 +48,7 @@ if [ "$#" -eq 0 ]; then
     # shed cold starts with a retry-after, and recover to closed; and an
     # all-defaults-off run must move ZERO resilience counters (the
     # BENCH_e2e.json-baselines-unchanged fast-fail)
-    if ! env "${JAX_CACHE_ENV[@]}" \
-        PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
+    if ! env PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
         python benchmarks/chaos_matrix.py --smoke; then
         echo "FAIL: chaos matrix smoke regression (see above)" >&2
         exit 1
@@ -75,8 +57,7 @@ if [ "$#" -eq 0 ]; then
     # the peer tier must stay byte-identical to the serial oracle (with
     # and without a peer crashed mid-transfer) and keep origin GETs
     # within 2x the unique chunk count (4x for the crashed-peer phase)
-    if ! env "${JAX_CACHE_ENV[@]}" \
-        PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
+    if ! env PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
         python benchmarks/coldstart_storm.py --smoke; then
         echo "FAIL: cold-start storm smoke regression (see above)" >&2
         exit 1
@@ -86,16 +67,14 @@ if [ "$#" -eq 0 ]; then
     # 3x), checkpoint dedup falling with encrypt-skips, and a GC
     # generation roll under a frozen live restore honoring the pin/alarm
     # protocol
-    if ! env "${JAX_CACHE_ENV[@]}" \
-        PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
+    if ! env PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
         python benchmarks/publish_pipeline.py --smoke; then
         echo "FAIL: publish pipeline smoke regression (see above)" >&2
         exit 1
     fi
     # dedup-statistics gate: the Fig-5 creation-time numbers stay in the
     # paper's ballpark (re-upload fraction, unique-chunk mean)
-    if ! env "${JAX_CACHE_ENV[@]}" \
-        PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
+    if ! env PYTHONPATH="src:.${PYTHONPATH:+:$PYTHONPATH}" \
         python benchmarks/dedup_cdf.py --smoke; then
         echo "FAIL: dedup statistics smoke regression (see above)" >&2
         exit 1

@@ -59,8 +59,8 @@ model. ``BatchDecoder``, ``ReadPolicy.decode_backend``,
 ``--decode-backend`` flag all select one registered backend BY NAME
 instead of threading ``encrypt_many``/``sha_backend`` hooks separately:
 
-* ``"python"`` (alias ``"numpy"``, the default): batched numpy T-table
-  AES + hashlib verify. hashlib releases the GIL and runs at memory
+* ``"python"`` (alias ``"numpy"``): batched numpy T-table AES +
+  hashlib verify. hashlib releases the GIL and runs at memory
   bandwidth — the CPU fast path.
 * ``"xla"`` (alias ``"jax"``): the ``repro.kernels.aes`` jit'd T-table
   gather pass + hashlib verify (single-threaded tiles: XLA manages its
@@ -69,15 +69,16 @@ instead of threading ``encrypt_many``/``sha_backend`` hooks separately:
 * ``"bitsliced"``: the gather-free Pallas kernels — bit-plane AES-CTR
   (Boyar–Peralta S-box circuit, ``kernels/aes/bitslice_pallas``) +
   lockstep SHA-256 verify (``kernels/sha256``). The TPU VPU lowering;
-  off-TPU both kernels run under the Pallas interpreter.
+  off-TPU both kernels run under the Pallas interpreter (what the CPU
+  tests drive; on TPU a kernel compiles or raises, never interprets).
 * ``"bitsliced-fused"`` (alias ``"fused"``): ONE tiled pass
   (``kernels/fused``) producing digests AND plaintext from a single
   walk over each ciphertext — the lockstep SHA lanes and the bitsliced
   keystream XOR share the tile, halving memory traffic versus the
   ``sha_many``-then-``encrypt_many`` pair, with per-CHUNK round keys
   broadcast inside the kernel instead of repeated per block.
-* ``"auto"``: probe the jax platform — ``bitsliced-fused`` on TPU,
-  ``xla`` on GPU, ``python`` on CPU.
+* ``"auto"`` (the ``ServiceConfig`` default): probe the jax platform —
+  ``bitsliced-fused`` on TPU, ``xla`` on GPU, ``python`` on CPU.
 * ``"serial"``: the per-chunk ``decrypt_chunk`` oracle — PR 1's caller-
   thread behavior, kept for byte-identity tests and benchmarks (not a
   registry object; it bypasses the batched pass entirely).
@@ -215,34 +216,25 @@ def get_backend(name: str) -> DecodeBackend:
     return _REGISTRY[resolve_backend_name(name)]
 
 
-def enable_persistent_compilation_cache(cache_dir: str) -> bool:
-    """Opt-in jax persistent compilation cache: jit artifacts land in
-    ``cache_dir`` and survive the process, so the ~4-7s per-lane-bucket
-    first-compile of the Pallas decode kernels taxes ONE process per
-    machine instead of every process's first restore. Returns True when
-    the cache was enabled (jax present and the config knob exists).
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
-    Off by default: a shared/global cache dir is a policy decision
-    (stale-artifact and disk-growth tradeoffs), so callers opt in via a
-    flag (``serve.py --jax-compile-cache``, ``decode_kernels.py
-    --compile-cache``)."""
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.abspath(os.path.expanduser(cache_dir)))
-    except Exception as e:                     # jax absent / knob renamed
-        warnings.warn(f"persistent compilation cache unavailable: {e}")
-        return False
-    # best-effort tuning: cache even fast compiles (the lane buckets are
-    # many small jits); knob names vary across jax versions, so failures
-    # here must not disable the cache itself
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:
-            pass
-    return True
+
+def enable_persistent_compilation_cache() -> str:
+    """Place jax's persistent compilation cache, so the Pallas decode
+    kernels and the serving step compile once per machine, not once per
+    process. The one place the program sets it: ``JAX_COMPILATION_CACHE_DIR``
+    when the environment sets it (jax reads that itself), otherwise the
+    fixed ``.jax_cache/`` at the checkout root — a path that never moves,
+    since the path is part of what a later run looks up. Entry points
+    call this at start-up; the tests never do. Returns the directory."""
+    import jax
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = CHECKOUT_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
 
 
 def _load_xla():

@@ -184,7 +184,7 @@ class ServiceConfig:
     l2_flash_bytes: int | None = None
     max_coldstarts: int = 4             # admission control (§4.2)
     fetch_concurrency: int = 16         # 0 = unbounded origin reads
-    decode_backend: str = "numpy"
+    decode_backend: str = "auto"        # platform probe (core.decode)
     decode_threads: int | None = None
     # "auto" = per-backend autotuned tile (decode.autotune_tile_bytes:
     # small timed sweep at first use, cached per process). Any explicit

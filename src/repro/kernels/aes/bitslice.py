@@ -259,12 +259,23 @@ def sub_bytes(b: list) -> list:
     return [s7, s6, s5, s4, s3, s2, s1, s0]    # back to LSB-first
 
 
+# Static permutations of the 16 byte-position rows (p = 4c + r):
+# ShiftRows moves row r left by r columns; MixColumns reads rows r+k of
+# the same column. Applied as row slices + one concatenate — a sublane
+# shuffle Mosaic lowers (it rejects the empty slice a roll by 0 makes).
+_SHIFT_ROWS = [4 * ((p // 4 + p % 4) % 4) + p % 4 for p in range(16)]
+_MIX_ROWS = [[4 * (p // 4) + (p % 4 + k) % 4 for p in range(16)]
+             for k in (1, 2, 3)]
+
+
+def _permute_rows(a, perm, xp):
+    return xp.concatenate([a[i:i + 1] for i in perm], axis=0)
+
+
 def shift_rows(a, xp=jnp):
     """One plane (16, L) through ShiftRows: a static shuffle of the 16
     byte positions (row r left-rotates by r columns)."""
-    a4 = a.reshape(4, 4, *a.shape[1:])          # [col, row, L]
-    rows = [xp.roll(a4[:, r], -r, axis=0) for r in range(4)]
-    return xp.stack(rows, axis=1).reshape(a.shape)
+    return _permute_rows(a, _SHIFT_ROWS, xp)
 
 
 def xtime_bits(v: list) -> list:
@@ -277,16 +288,10 @@ def xtime_bits(v: list) -> list:
 def mix_columns(b: list, xp=jnp) -> list:
     """8 planes (16, L) through MixColumns:
     s'_r = xt(s_r ^ s_r+1) ^ s_r+1 ^ s_r+2 ^ s_r+3 (indices mod 4)."""
-    a4 = [x.reshape(4, 4, *x.shape[1:]) for x in b]   # [col, row, L]
-    rows = [[a4[i][:, r] for i in range(8)] for r in range(4)]
-    out_rows = []
-    for r in range(4):
-        s0, s1 = rows[r], rows[(r + 1) % 4]
-        s2, s3 = rows[(r + 2) % 4], rows[(r + 3) % 4]
-        xt = xtime_bits([s0[i] ^ s1[i] for i in range(8)])
-        out_rows.append([xt[i] ^ s1[i] ^ s2[i] ^ s3[i] for i in range(8)])
-    return [xp.stack([out_rows[r][i] for r in range(4)],
-                     axis=1).reshape(b[i].shape) for i in range(8)]
+    s1, s2, s3 = ([_permute_rows(x, perm, xp) for x in b]
+                  for perm in _MIX_ROWS)
+    xt = xtime_bits([b[i] ^ s1[i] for i in range(8)])
+    return [xt[i] ^ s1[i] ^ s2[i] ^ s3[i] for i in range(8)]
 
 
 def add_round_key(b: list, rk) -> list:
@@ -341,28 +346,27 @@ def broadcast_pad(blocks_u8: np.ndarray, round_keys: np.ndarray,
     return blocks_u8, round_keys
 
 
-def encrypt_planes_body(planes, rk_planes, rounds: int):
-    """Traceable plane pipeline: (8, 16, W) x (R+1, 8, 16, W) ->
-    (8, 16, W). The middle rounds run under a ``fori_loop`` so XLA
-    compiles ONE round body (~370 ops), not rounds-many. Plain function
-    (no jit) so Pallas kernel bodies — which cannot nest a jit — and
-    jit'd wrappers share the exact same trace."""
-    x = jnp.stack(add_round_key([planes[i] for i in range(8)],
-                                rk_planes[0]))
+def encrypt_planes_body(planes, rk_at, rounds: int) -> list:
+    """Traceable plane pipeline: 8 (16, W) planes, ``rk_at(r)`` -> round
+    ``r``'s (8, 16, W) key planes -> 8 encrypted planes. The middle
+    rounds run under a ``fori_loop`` so the compiler sees ONE round body
+    (~370 ops), not rounds-many; ``rk_at`` reads each round's keys
+    inside the loop (a ref index in a Pallas kernel, an array index in
+    a jit), so kernel and reference share the exact same trace."""
+    x = tuple(add_round_key(list(planes), rk_at(0)))
 
     def body(r, x):
-        rk = jax.lax.dynamic_index_in_dim(rk_planes, r, 0, keepdims=False)
-        return jnp.stack(middle_round([x[i] for i in range(8)], rk))
+        return tuple(middle_round(list(x), rk_at(r)))
 
     x = jax.lax.fori_loop(1, rounds, body, x)
-    return jnp.stack(final_round([x[i] for i in range(8)],
-                                 rk_planes[rounds]))
+    return final_round(list(x), rk_at(rounds))
 
 
 @functools.partial(jax.jit, static_argnames=("rounds",))
 def encrypt_planes(planes, rk_planes, rounds: int):
     """jit'd plane-level reference over ``encrypt_planes_body``."""
-    return encrypt_planes_body(planes, rk_planes, rounds)
+    return jnp.stack(encrypt_planes_body(
+        [planes[i] for i in range(8)], lambda r: rk_planes[r], rounds))
 
 
 def encrypt_blocks_bitsliced(blocks_u8: np.ndarray,

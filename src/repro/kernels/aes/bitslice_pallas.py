@@ -11,9 +11,8 @@ grid step.
 
 Planes are int32 in/out (the TPU-native word type; the uint32 bit
 patterns pass through bitwise ops unchanged) — adapters in ``ops.py``
-``.view()`` between the two. ``interpret=True`` is the CPU fallback:
-the same kernel runs under the Pallas interpreter (still jit-compiled
-by XLA), which is how every test and the CPU decode backend drive it.
+``.view()`` between the two. Off-TPU the adapters run the same kernel
+under the Pallas interpreter, which is how the CPU tests drive it.
 """
 from __future__ import annotations
 
@@ -23,29 +22,17 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.aes.bitslice import (
-    add_round_key,
-    final_round,
-    middle_round,
-)
+from repro.kernels.aes.bitslice import encrypt_planes_body
 
 BLOCK_WORDS = 256          # lane words per tile = 8192 AES blocks
 
 
 def _aes_bs_kernel(x_ref, rk_ref, o_ref, *, rounds):
-    rkv = rk_ref[...]                                   # (R+1, 8, 16, blk)
-    b = [x_ref[i] for i in range(8)]                    # (16, blk) each
-    x = jnp.stack(add_round_key(b, rkv[0]))
-
-    # fori over the middle rounds: the compiler sees ONE round body
-    # (~370 vector ops), not rounds-many — an order of magnitude off the
-    # compile time with identical math
-    def body(r, x):
-        rk = jax.lax.dynamic_index_in_dim(rkv, r, 0, keepdims=False)
-        return jnp.stack(middle_round([x[i] for i in range(8)], rk))
-
-    x = jax.lax.fori_loop(1, rounds, body, x)
-    out = final_round([x[i] for i in range(8)], rkv[rounds])
+    # each round's key planes are read from the ref inside the round
+    # loop: Mosaic lowers a dynamic ref index, not a dynamic slice of a
+    # loaded value
+    out = encrypt_planes_body([x_ref[i] for i in range(8)],
+                              lambda r: rk_ref[r], rounds)
     for i in range(8):
         o_ref[i] = out[i]
 
@@ -73,4 +60,5 @@ def encrypt_planes_pallas(planes: jax.Array, rk_planes: jax.Array, *,
         out_specs=pl.BlockSpec((8, 16, blk), lambda i: (0, 0, i)),
         out_shape=jax.ShapeDtypeStruct((8, 16, w), jnp.int32),
         interpret=interpret,
+        name="aes_bitsliced",
     )(planes, rk_planes)

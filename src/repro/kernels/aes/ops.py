@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import on_tpu
+from repro.kernels import pallas_interpret
 from repro.kernels.aes import aesjax, bitslice
 
 _MIN_BUCKET = 256
@@ -90,14 +90,14 @@ def encrypt_many_bitsliced(blocks_u8: np.ndarray, rks: np.ndarray, *,
       via the ``per_chunk_rks`` attribute, skipping its host-side
       ``np.repeat`` of 60-word schedules per block.
 
-    Lane-word and chunk counts are bucketed to powers of two so the jit
-    compiles O(log batch) times. ``interpret=None`` auto-selects the
-    Pallas interpreter off-TPU (the CPU fallback)."""
+    Lane-word and chunk counts are bucketed to powers of two, both from
+    256, so the jit compiles O(log batch) times and a batch of up to 256
+    per-block schedules compiles once per key size. ``interpret=None``
+    compiles on TPU and interprets elsewhere."""
     n = blocks_u8.shape[0]
     if n == 0:
         return np.empty((0, 16), np.uint8)
-    if interpret is None:
-        interpret = not on_tpu()
+    interpret = pallas_interpret("aes", interpret)
     if counts is None:
         if rks.ndim == 2:
             rks = rks[None]
@@ -117,7 +117,7 @@ def encrypt_many_bitsliced(blocks_u8: np.ndarray, rks: np.ndarray, *,
             [blocks_u8, np.repeat(blocks_u8[-1:], pad, axis=0)])
         idx = np.concatenate([idx, np.full(pad, idx[-1], np.int32)])
     c = rks.shape[0]
-    cb = 8
+    cb = _MIN_WORDS * 32
     while cb < c:
         cb <<= 1
     if cb > c:

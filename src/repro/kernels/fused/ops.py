@@ -8,20 +8,52 @@ plaintexts byte-identical to the serial CTR oracle. The caller
 chunk names BEFORE releasing any plaintext, so per-chunk tamper
 detection and the eviction/retry semantics are unchanged.
 
-Marshalling mirrors ``sha256.ops.sha256_many_pallas``: one padded
-schedule-word tensor per tile, lanes bucketed to powers of two and
-message blocks to coarse steps so the pass retraces O(log) times.
+Marshalling is the SHA kernel's (``sha256.ops.pack_messages``): one
+padded byte row per chunk, word swap and transpose on the device. The
+round keys travel as per-chunk 0/-1 bit planes (``round_key_planes``).
 """
 from __future__ import annotations
 
+import functools
+
+import jax
 import numpy as np
 
 from repro.core.crypto.aes import expand_key
-from repro.core.crypto.sha256v import _pad
-from repro.kernels import on_tpu
-from repro.kernels.aes import bitslice
-from repro.kernels.sha256.ops import _bucket_blocks, _bucket_lanes
+from repro.kernels import on_tpu, pallas_interpret, record_route
 from repro.kernels.fused.fusedp import fused_lanes_jit, fused_lanes_pallas
+from repro.kernels.sha256.ops import (
+    bytes_from_words,
+    digests_to_bytes,
+    pack_messages,
+    words_from_bytes,
+)
+
+
+def round_key_planes(keys: list, lanes: int) -> np.ndarray:
+    """AES key schedules of N chunk keys -> (rounds+1, 8, 16, lanes)
+    int32: ``[r, i, p]`` is -(bit i of round ``r``'s key byte p), one
+    lane per chunk (lanes past N are zero)."""
+    expanded = {k: expand_key(k) for k in set(keys)}
+    rks = np.stack([expanded[k] for k in keys])           # (N, R+1, 4)
+    n, nr, _ = rks.shape
+    kb = rks.astype(">u4").view(np.uint8).reshape(n, nr, 16)   # byte p
+    bits = np.unpackbits(kb[..., None], axis=-1, bitorder="little")
+    planes = np.zeros((nr, 8, 16, lanes), np.int32)
+    planes[..., :n] = -bits.transpose(1, 3, 2, 0).astype(np.int32)
+    return planes
+
+
+@functools.partial(jax.jit, static_argnames=("rounds", "pallas", "interpret"))
+def _fused_device(buf, nb, rk_planes, *, rounds: int, pallas: bool,
+                  interpret: bool):
+    words = words_from_bytes(buf)
+    if pallas:
+        dig, plain = fused_lanes_pallas(words, nb, rk_planes, rounds=rounds,
+                                        interpret=interpret)
+    else:
+        dig, plain = fused_lanes_jit(words, nb, rk_planes, rounds=rounds)
+    return dig, bytes_from_words(plain)
 
 
 def fused_verify_decrypt(cts: list, keys: list, *,
@@ -29,50 +61,24 @@ def fused_verify_decrypt(cts: list, keys: list, *,
                          pallas: bool | None = None) -> tuple:
     """One fused device pass over N ciphertext chunks: returns
     (digests, plaintexts) — digests[i] == sha256(cts[i]).digest() and
-    plaintexts[i] == AES-256-CTR(keys[i], zero IV) ^ cts[i], both as
-    bytes. ``pallas=None`` routes through the Pallas kernel on TPU and
-    the whole-batch XLA jit elsewhere; ``interpret`` only applies to
-    the Pallas route."""
+    plaintexts[i] == AES-CTR(keys[i], zero IV) ^ cts[i], both as bytes.
+    ``pallas=None`` routes through the Pallas kernel on TPU and the
+    whole-tile XLA jit elsewhere; ``interpret`` only applies to the
+    Pallas route."""
     n = len(cts)
     if n == 0:
         return [], []
     if pallas is None:
         pallas = on_tpu()
-    if interpret is None:
-        interpret = not on_tpu()
-    padded = [_pad(ct) for ct in cts]
-    nbl = [len(p) // 64 for p in padded]
-    maxb = _bucket_blocks(max(nbl))
-    lanes = _bucket_lanes(n)
-    words = np.zeros((maxb, 16, lanes), np.uint32)
-    for i, p in enumerate(padded):
-        w = np.frombuffer(p, dtype=">u4").reshape(-1, 16)
-        words[:w.shape[0], :, i] = w
-    nb = np.zeros((1, lanes), np.int32)
-    nb[0, :n] = nbl
-    expanded: dict[bytes, np.ndarray] = {}
-    per_key = []
-    for k in keys:
-        rk = expanded.get(k)
-        if rk is None:
-            rk = expanded[k] = expand_key(k)
-        per_key.append(rk)
-    rks = np.stack(per_key)
-    if lanes > n:       # edge-repeat: padded lanes run a discarded chunk
-        rks = np.concatenate(
-            [rks, np.repeat(rks[-1:], lanes - n, axis=0)])
-    rounds = rks.shape[1] - 1
-    rkp = bitslice.pack_round_keys(np.ascontiguousarray(rks)).view(np.int32)
     if pallas:
-        dig, plain = fused_lanes_pallas(words.view(np.int32), nb, rkp,
-                                        maxb=maxb, rounds=rounds,
-                                        interpret=interpret)
+        interpret = pallas_interpret("fused", interpret)
     else:
-        dig, plain = fused_lanes_jit(words.view(np.int32), nb, rkp,
-                                     maxb=maxb, rounds=rounds)
-    dig_w = np.asarray(dig).view(np.uint32).T[:n].astype(">u4")
-    digests = [dig_w[i].tobytes() for i in range(n)]
-    plain_w = np.ascontiguousarray(
-        np.asarray(plain).view(np.uint32).transpose(2, 0, 1)).astype(">u4")
-    plains = [plain_w[i].tobytes()[:len(ct)] for i, ct in enumerate(cts)]
-    return digests, plains
+        interpret = False
+        record_route("fused", "xla-jit")
+    buf, nb = pack_messages(cts)
+    rk = round_key_planes(keys, buf.shape[0])
+    dig, plain = _fused_device(buf, nb, rk, rounds=rk.shape[0] - 1,
+                               pallas=pallas, interpret=interpret)
+    plain = np.asarray(plain).view(np.uint8)
+    return (digests_to_bytes(dig, n),
+            [plain[i, :len(ct)].tobytes() for i, ct in enumerate(cts)])

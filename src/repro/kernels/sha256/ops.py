@@ -3,20 +3,25 @@
 ``sha256_many_pallas`` is a drop-in for the ``sha_many`` hook of
 ``convergent.decrypt_chunks`` (and so of the ``bitsliced`` decode
 backend): list of byte strings in, list of 32-byte digests out,
-byte-identical to hashlib. The batched padding happens host-side ONCE
-(``sha256v._pad``), the schedule words are transposed lane-major, and
-batch dimensions are bucketed (lanes to powers of two, message blocks
+byte-identical to hashlib. The host's part is one memcpy per message
+into a zeroed (lanes, maxb*64) byte buffer plus its SHA padding
+(``pack_messages``); the big-endian word swap and the transpose to the
+kernel's word-major layout run on the device (``words_from_bytes``).
+Batch dimensions are bucketed (lanes to powers of two, message blocks
 to coarse steps) so the kernel retraces O(log) times, not per shape.
 """
 from __future__ import annotations
 
+import functools
+
+import jax
 import numpy as np
 
-from repro.core.crypto.sha256v import _pad
-from repro.kernels import on_tpu
-from repro.kernels.sha256.sha256p import sha256_lanes_pallas
+from repro.kernels import pallas_interpret
+from repro.kernels.sha256.sha256p import STEP_BLOCKS, sha256_lanes_pallas
 
-_MIN_LANES = 32
+_MIN_LANES = 8
+_MIN_BLOCKS = 8
 
 
 def _bucket_lanes(n: int) -> int:
@@ -27,37 +32,77 @@ def _bucket_lanes(n: int) -> int:
 
 
 def _bucket_blocks(b: int) -> int:
-    """Coarse maxb buckets: powers of two up to 16, then multiples of 16
-    (chunk batches are usually same-length, so this compiles once for
-    the common tile shape instead of per distinct message length)."""
-    p = 1
-    while p < min(b, 16):
+    """Coarse maxb buckets: powers of two from 8 up to ``STEP_BLOCKS``,
+    then multiples of ``STEP_BLOCKS`` (chunk batches are usually
+    same-length, so this compiles once for the common tile shape; the
+    kernel walks blocks one ``STEP_BLOCKS`` step at a time)."""
+    if b > STEP_BLOCKS:
+        return -(-b // STEP_BLOCKS) * STEP_BLOCKS
+    p = _MIN_BLOCKS
+    while p < b:
         p <<= 1
-    if b <= 16:
-        return p
-    return ((b + 15) // 16) * 16
+    return p
+
+
+def pack_messages(datas: list) -> tuple:
+    """SHA-pad N byte strings into one zeroed (lanes, maxb*64) uint8
+    buffer, one message per row. Returns (buffer viewed as native int32
+    words, (1, lanes) int32 block counts)."""
+    n = len(datas)
+    nbl = [(len(d) + 9 + 63) // 64 for d in datas]
+    maxb = _bucket_blocks(max(nbl))
+    lanes = _bucket_lanes(n)
+    buf = np.zeros((lanes, maxb * 64), np.uint8)
+    for i, (d, nb) in enumerate(zip(datas, nbl)):
+        ln = len(d)
+        buf[i, :ln] = np.frombuffer(d, np.uint8)
+        buf[i, ln] = 0x80
+        buf[i, nb * 64 - 8:nb * 64] = np.frombuffer(
+            (8 * ln).to_bytes(8, "big"), np.uint8)
+    nb_arr = np.zeros((1, lanes), np.int32)
+    nb_arr[0, :n] = nbl
+    return buf.view(np.int32), nb_arr
+
+
+def bswap32(x):
+    """Byte-reverse every int32 word (big-endian <-> native order)."""
+    srl = jax.lax.shift_right_logical
+    return ((x << 24) | ((x & 0xFF00) << 8)
+            | (srl(x, 8) & 0xFF00) | srl(x, 24))
+
+
+def words_from_bytes(buf):
+    """(lanes, maxb*16) native int32 view of message bytes -> (16, maxb,
+    lanes) big-endian schedule words, the kernels' word-major layout."""
+    lanes = buf.shape[0]
+    return bswap32(buf).reshape(lanes, -1, 16).transpose(2, 1, 0)
+
+
+def bytes_from_words(words):
+    """Inverse of ``words_from_bytes``: (16, maxb, lanes) big-endian
+    words -> (lanes, maxb*16) int32 whose native bytes are the stream."""
+    lanes = words.shape[-1]
+    return bswap32(words.transpose(2, 1, 0)).reshape(lanes, -1)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _sha_device(buf, nb, *, interpret: bool):
+    return sha256_lanes_pallas(words_from_bytes(buf), nb,
+                               interpret=interpret)
+
+
+def digests_to_bytes(dig, n: int) -> list:
+    """(8, lanes) int32 digest words -> N 32-byte digests."""
+    d = np.asarray(dig).view(np.uint32).T[:n].astype(">u4")
+    return [d[i].tobytes() for i in range(n)]
 
 
 def sha256_many_pallas(datas: list, *, interpret: bool | None = None) -> list:
     """Digests of N byte strings through the Pallas lockstep kernel.
-    ``interpret=None`` auto-selects the interpreter off-TPU (the CPU
-    fallback); pass False to require the compiled TPU lowering."""
+    ``interpret=None`` compiles on TPU and interprets elsewhere."""
     n = len(datas)
     if n == 0:
         return []
-    if interpret is None:
-        interpret = not on_tpu()
-    padded = [_pad(d) for d in datas]
-    nbl = [len(p) // 64 for p in padded]
-    maxb = _bucket_blocks(max(nbl))
-    lanes = _bucket_lanes(n)
-    words = np.zeros((maxb, 16, lanes), np.uint32)
-    for i, p in enumerate(padded):
-        w = np.frombuffer(p, dtype=">u4").reshape(-1, 16)
-        words[:w.shape[0], :, i] = w
-    nb = np.zeros((1, lanes), np.int32)
-    nb[0, :n] = nbl
-    out = sha256_lanes_pallas(words.view(np.int32), nb, maxb=maxb,
-                              interpret=interpret)
-    dig = np.asarray(out).view(np.uint32).T[:n].astype(">u4")
-    return [dig[i].tobytes() for i in range(n)]
+    interpret = pallas_interpret("sha256", interpret)
+    buf, nb = pack_messages(datas)
+    return digests_to_bytes(_sha_device(buf, nb, interpret=interpret), n)

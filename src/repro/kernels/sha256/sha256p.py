@@ -7,11 +7,18 @@ functions advance together as (N,)-shaped uint32 lanes. This kernel is
 that exact round structure — pure 32-bit rotate/xor/add, the shape the
 VPU natively executes — with the lanes on the TPU vector axis:
 
-* input is the padded message schedule transposed to (maxb, 16, N)
-  words, so every round's 16-word window is one contiguous (16, blk)
-  VMEM tile slice;
-* a ``fori_loop`` walks the message blocks; the 64 rounds inside are
-  statically unrolled (the compiler sees one block body);
+* input is the padded message schedule, word-major: (16, maxb, N) int32
+  — word ``t`` of message block ``b`` of lane ``c`` at ``[t, b, c]`` —
+  so one block's 16 words are 16 single-row loads of (1, lanes);
+* the grid is (lane tiles, block steps): the second axis walks the
+  message blocks ``STEP_BLOCKS`` at a time, sequentially, and the digest
+  state lives in the output block, which stays resident in VMEM across
+  that axis. A 512 KiB chunk is 129 steps of a (16, 64, lanes) tile, not
+  one 8256-block tile;
+* inside a step a ``fori_loop`` walks the blocks, reading each block's
+  words from the ref (Mosaic lowers a dynamic row load; it does not
+  lower a dynamic slice of a loaded value); each block's 64 rounds run
+  as 16-round groups (``sha_compress``);
 * per-lane message lengths are handled exactly like the reference:
   lanes whose final padded block has been absorbed FREEZE via a masked
   state update (``nblocks > b``), so one launch hashes mixed-length
@@ -19,9 +26,9 @@ VPU natively executes — with the lanes on the TPU vector axis:
 * all arithmetic is int32 (TPU-native; uint32 adds wrap identically in
   two's complement) — adapters ``.view()`` at the boundary.
 
-``interpret=True`` is the CPU fallback: the same kernel under the
-Pallas interpreter, jit-compiled by XLA. Oracle-tested against hashlib
-across padding boundaries in ``tests/test_bitslice_kernels.py``.
+Off-TPU the adapters run the same kernel under the Pallas interpreter.
+Oracle-tested against hashlib across padding boundaries in
+``tests/test_bitslice_kernels.py``.
 """
 from __future__ import annotations
 
@@ -31,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.crypto.sha256v import _H0, _K
 
@@ -38,6 +46,7 @@ _K32 = [int(k) for k in _K.view(np.int32)]
 _H032 = [int(h) for h in _H0.view(np.int32)]
 
 LANE_BLOCK = 128           # message lanes per grid step
+STEP_BLOCKS = 64           # message blocks per sequential grid step
 
 
 def _rotr(x, n: int):
@@ -48,66 +57,127 @@ def _shr(x, n: int):
     return jax.lax.shift_right_logical(x, n)
 
 
-def sha_block_fold(wv, nb, maxb: int):
-    """Fold ``maxb`` message blocks through the lockstep compression
-    function: wv (maxb, 16, L) int32 schedule words, nb (L,) int32
-    per-lane block counts -> tuple of 8 (L,) int32 digest lanes. Plain
-    traceable function so both ``_sha_kernel`` and the fused
-    verify+decrypt kernel (``kernels.fused``) share the exact rounds."""
-
-    def block_body(b, state):
-        wb = jax.lax.dynamic_index_in_dim(wv, b, 0, keepdims=False)
-        w = [wb[t] for t in range(16)]        # (blk,) lanes
-        for t in range(16, 64):
-            s0 = _rotr(w[t - 15], 7) ^ _rotr(w[t - 15], 18) ^ _shr(w[t - 15], 3)
-            s1 = _rotr(w[t - 2], 17) ^ _rotr(w[t - 2], 19) ^ _shr(w[t - 2], 10)
-            w.append(w[t - 16] + s0 + w[t - 7] + s1)
-        a, bb, c, d, e, f, g, h = state
-        for t in range(64):
-            s1 = _rotr(e, 6) ^ _rotr(e, 11) ^ _rotr(e, 25)
-            ch = (e & f) ^ (~e & g)
-            t1 = h + s1 + ch + jnp.int32(_K32[t]) + w[t]
-            s0 = _rotr(a, 2) ^ _rotr(a, 13) ^ _rotr(a, 22)
-            maj = (a & bb) ^ (a & c) ^ (bb & c)
-            t2 = s0 + maj
-            a, bb, c, d, e, f, g, h = t1 + t2, a, bb, c, d + t1, e, f, g
-        new = (a, bb, c, d, e, f, g, h)
-        active = nb > b                       # frozen lanes keep state
-        return tuple(jnp.where(active, s + n_, s)
-                     for s, n_ in zip(state, new))
-
-    zeros = jnp.zeros_like(nb)
-    state0 = tuple(zeros + jnp.int32(h) for h in _H032)
-    return jax.lax.fori_loop(0, maxb, block_body, state0)
+def sha_init(like) -> tuple:
+    """The 8 initial digest lanes, each shaped like `like`."""
+    return tuple(jnp.full(like.shape, h, jnp.int32) for h in _H032)
 
 
-def _sha_kernel(words_ref, nb_ref, out_ref, *, maxb):
-    state = sha_block_fold(words_ref[...], nb_ref[0], maxb)
-    for i in range(8):
-        out_ref[i] = state[i]
+def _rounds(w: list, state: tuple, k_at) -> tuple:
+    """16 compression rounds over schedule words ``w``; ``k_at(i)`` is
+    the round constant of the i-th of them."""
+    a, bb, c, d, e, f, g, h = state
+    for i in range(16):
+        s1 = _rotr(e, 6) ^ _rotr(e, 11) ^ _rotr(e, 25)
+        ch = (e & f) ^ (~e & g)
+        t1 = h + s1 + ch + k_at(i) + w[i]
+        s0 = _rotr(a, 2) ^ _rotr(a, 13) ^ _rotr(a, 22)
+        maj = (a & bb) ^ (a & c) ^ (bb & c)
+        a, bb, c, d, e, f, g, h = t1 + s0 + maj, a, bb, c, d + t1, e, f, g
+    return a, bb, c, d, e, f, g, h
 
 
-@functools.partial(jax.jit, static_argnames=("maxb", "interpret", "block"))
-def sha256_lanes_pallas(words: jax.Array, nblocks: jax.Array, *,
-                        maxb: int, interpret: bool = False,
-                        block: int = LANE_BLOCK) -> jax.Array:
-    """words: (maxb, 16, N) int32 big-endian schedule words (zero past
-    each lane's final block); nblocks: (1, N) int32 blocks per lane.
-    Returns (8, N) int32 digest words. N must split into power-of-two
-    lane tiles (callers bucket; see ``ops.sha256_many_pallas``)."""
-    n = words.shape[-1]
+def _next_schedule(w: list) -> list:
+    """The next 16 message-schedule words from the previous 16."""
+    w = list(w)
+    for t in range(16, 32):
+        s0 = _rotr(w[t - 15], 7) ^ _rotr(w[t - 15], 18) ^ _shr(w[t - 15], 3)
+        s1 = _rotr(w[t - 2], 17) ^ _rotr(w[t - 2], 19) ^ _shr(w[t - 2], 10)
+        w.append(w[t - 16] + s0 + w[t - 7] + s1)
+    return w[16:]
+
+
+def sha_compress(w: list, state: tuple) -> tuple:
+    """One compression: 16 schedule words (lane arrays) folded into the
+    8 state lanes; returns the updated (added) state.
+
+    The first 48 rounds run as a 3-trip loop of 16 rounds plus the next
+    16 schedule words, the last 16 unrolled after it. Unrolled in one
+    piece, XLA:CPU copies the whole 64-round graph into one fusion per
+    state word and compiles each for minutes — the route every CPU test
+    takes; the loop bounds each fusion to one 16-round group."""
+
+    def group(g, carry):
+        w, st = carry
+
+        def k_at(i):
+            k = jnp.int32(_K32[32 + i])
+            for gg in (1, 0):
+                k = jnp.where(g == gg, jnp.int32(_K32[16 * gg + i]), k)
+            return k
+
+        return tuple(_next_schedule(w)), _rounds(w, st, k_at)
+
+    w, st = jax.lax.fori_loop(0, 3, group, (tuple(w), state))
+    st = _rounds(w, st, lambda i: jnp.int32(_K32[48 + i]))
+    return tuple(s + n for s, n in zip(state, st))
+
+
+def sha_fold(load, nb, state: tuple, b0, nblocks: int) -> tuple:
+    """Fold `nblocks` message blocks into `state`: ``load(j)`` returns
+    the 16 schedule words of local block ``j`` (global block ``b0 + j``)
+    as lane arrays; ``nb`` holds each lane's block count, and lanes
+    past their final block keep their state. Plain traceable function:
+    the SHA kernel, the fused kernel (``kernels.fused``) and its XLA
+    route share these exact rounds through their own ``load``."""
+
+    def body(j, st):
+        new = sha_compress(load(j), st)
+        active = nb > b0 + j                  # frozen lanes keep state
+        return tuple(jnp.where(active, n, s) for s, n in zip(st, new))
+
+    return jax.lax.fori_loop(0, nblocks, body, state)
+
+
+def tile_shape(maxb: int, n: int, block: int = LANE_BLOCK) -> tuple:
+    """(block steps, lanes per tile) for a (16, maxb, n) word tensor:
+    a step is ``STEP_BLOCKS`` blocks (or all of a shorter message) and a
+    lane tile is ``block`` lanes (or all of a narrower batch)."""
+    bt = min(STEP_BLOCKS, maxb)
+    assert maxb % bt == 0 and bt % 8 == 0, maxb
     blk = min(block, n)
     while n % blk:
         blk //= 2
-    grid = (n // blk,)
+    return bt, blk
+
+
+def _sha_kernel(words_ref, nb_ref, out_ref, *, bt: int):
+    k = pl.program_id(1)
+
+    @pl.when(k == 0)
+    def _():
+        for i, h in enumerate(sha_init(nb_ref[...])):
+            out_ref[i:i + 1, :] = h
+
+    state = tuple(out_ref[i:i + 1, :] for i in range(8))
+    state = sha_fold(
+        lambda j: [words_ref[t, pl.ds(j, 1), :] for t in range(16)],
+        nb_ref[...], state, k * bt, bt)
+    for i in range(8):
+        out_ref[i:i + 1, :] = state[i]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "block"))
+def sha256_lanes_pallas(words: jax.Array, nblocks: jax.Array, *,
+                        interpret: bool = False,
+                        block: int = LANE_BLOCK) -> jax.Array:
+    """words: (16, maxb, N) int32 big-endian schedule words (zero past
+    each lane's final block); nblocks: (1, N) int32 blocks per lane.
+    Returns (8, N) int32 digest words. ``maxb`` must be a multiple of 8
+    and, above ``STEP_BLOCKS``, of ``STEP_BLOCKS``; N must split into
+    power-of-two lane tiles (callers bucket; see ``ops``)."""
+    _, maxb, n = words.shape
+    bt, blk = tile_shape(maxb, n, block)
     return pl.pallas_call(
-        functools.partial(_sha_kernel, maxb=maxb),
-        grid=grid,
+        functools.partial(_sha_kernel, bt=bt),
+        grid=(n // blk, maxb // bt),
         in_specs=[
-            pl.BlockSpec((maxb, 16, blk), lambda i: (0, 0, i)),
-            pl.BlockSpec((1, blk), lambda i: (0, i)),
+            pl.BlockSpec((16, bt, blk), lambda i, k: (0, k, i)),
+            pl.BlockSpec((1, blk), lambda i, k: (0, i)),
         ],
-        out_specs=pl.BlockSpec((8, blk), lambda i: (0, i)),
+        out_specs=pl.BlockSpec((8, blk), lambda i, k: (0, i)),
         out_shape=jax.ShapeDtypeStruct((8, n), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="sha256_lanes",
     )(words, nblocks)

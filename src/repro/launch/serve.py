@@ -27,7 +27,10 @@ def main():
     ap.add_argument("--root", default=None)
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=8)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the architecture's small CPU-test "
+                         "config (--no-reduced: its published widths)")
     ap.add_argument("--l1-bytes", type=int, default=256 << 20,
                     help="shared worker-local L1 cache size (0 = no L1)")
     ap.add_argument("--l2-nodes", type=int, default=6,
@@ -72,10 +75,6 @@ def main():
                          "1:blackholed — apply that FaultPlan to worker "
                          "WID in the mesh (transfers from it fail and "
                          "fall through)")
-    ap.add_argument("--jax-compile-cache", default=None, metavar="DIR",
-                    help="enable jax's persistent compilation cache in "
-                         "DIR so jit'd decode kernels compile once per "
-                         "machine, not once per process (opt-in)")
     ap.add_argument("--max-coldstarts", type=int, default=4,
                     help="admission control: concurrent cold starts this "
                          "replica accepts before REJECTING (RejectingLimiter, "
@@ -87,16 +86,17 @@ def main():
                     help="per-restore fetch pipeline width")
     from repro.core.decode import known_backend_names
 
-    ap.add_argument("--decode-backend", default="numpy",
+    ap.add_argument("--decode-backend", default="auto",
                     choices=known_backend_names(),
                     help="post-fetch batch decode backend from the "
-                         "core.decode registry: python/numpy = T-table "
-                         "numpy + hashlib; bitsliced-fused/fused = ONE "
-                         "fused verify+decrypt pass per tile; "
-                         "AES + hashlib; xla/jax = jit'd gather pass; "
-                         "bitsliced = gather-free Pallas AES + lockstep "
-                         "SHA verify kernels; auto = probe the "
-                         "platform; serial = per-chunk oracle")
+                         "core.decode registry: auto (default) = probe "
+                         "the platform (bitsliced-fused on TPU, python "
+                         "on CPU); python/numpy = T-table numpy AES + "
+                         "hashlib; bitsliced-fused/fused = ONE fused "
+                         "verify+decrypt pass per tile; xla/jax = jit'd "
+                         "gather pass + hashlib; bitsliced = gather-free "
+                         "Pallas AES + lockstep SHA verify kernels; "
+                         "serial = per-chunk oracle")
     ap.add_argument("--max-batch-bytes", type=int, default=None,
                     help="decode tile size in bytes (default: per-"
                          "backend autotuned at first use — a small "
@@ -170,11 +170,9 @@ def main():
                          "re-publishes skip encryption across processes")
     args = ap.parse_args()
 
-    if args.jax_compile_cache:
-        from repro.core.decode import enable_persistent_compilation_cache
-        if enable_persistent_compilation_cache(args.jax_compile_cache):
-            print(f"jax persistent compilation cache: "
-                  f"{args.jax_compile_cache}")
+    from repro.core.decode import enable_persistent_compilation_cache
+    print(f"jax persistent compilation cache: "
+          f"{enable_persistent_compilation_cache()}")
 
     import jax
 
@@ -210,7 +208,7 @@ def main():
         else:
             blob, stats = create_image(state_to_tree(params), tenant="serve",
                                        tenant_key=key, store=store,
-                                       root=root, chunk_size=65536)
+                                       root=root)
             print(f"imaged {stats.total_chunks} chunks "
                   f"({stats.bytes_total/1e6:.1f} MB)")
 
@@ -287,7 +285,7 @@ def main():
     if pending_tree is not None:
         t0 = time.time()
         blob, stats = service.publish(pending_tree, tenant="serve",
-                                      tenant_key=key, chunk_size=65536)
+                                      tenant_key=key)
         print(f"published {stats.total_chunks} chunks "
               f"({stats.bytes_total/1e6:.1f} MB) in {time.time()-t0:.2f}s "
               f"[batched pipeline, {stats.unique_chunks} uploaded, "

@@ -66,7 +66,6 @@ def _sp_flash(q, k, v, cfg, *, causal, use_vjp):
     right causal offset. The lever for archs whose head count doesn't
     divide the TP axis (arctic 56, smollm 15): without it XLA replicates
     the whole attention across the model axis."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.sharding.constrain import active_policy
 
@@ -101,12 +100,12 @@ def _sp_flash(q, k, v, cfg, *, causal, use_vjp):
         return attn_mod.flash_attn(ql, kf, vf, causal=causal, q_offset=off,
                                    window=cfg.sliding_window)
 
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(P(bspec, axis, None, None),
-                             P(bspec, None, None, None),
-                             P(bspec, None, None, None)),
-                   out_specs=P(bspec, axis, None, None),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(P(bspec, axis, None, None),
+                                 P(bspec, None, None, None),
+                                 P(bspec, None, None, None)),
+                       out_specs=P(bspec, axis, None, None),
+                       check_vma=False)
     return fn(q, k, v)
 
 

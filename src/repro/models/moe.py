@@ -88,7 +88,6 @@ def _moe_shard_map(p, x, cfg: ModelConfig, dtype, mesh, policy, ep_axes):
     O(tokens/dp * k * d) instead of the SPMD global-sort fallback's
     all-gathers — the MoE hillclimb lever (§Perf).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     B, S, D = x.shape
@@ -164,13 +163,13 @@ def _moe_shard_map(p, x, cfg: ModelConfig, dtype, mesh, policy, ep_axes):
     ep_spec = ep_axis
     out_spec = P(batch_spec, ep_spec, None) if seq_split \
         else P(batch_spec, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(batch_spec, None, None), P(None, None),
                   P(ep_spec, None, None), P(ep_spec, None, None),
                   P(ep_spec, None, None)),
         out_specs=out_spec,
-        check_rep=False)
+        check_vma=False)
     y = fn(x, p["router"], wg, wu, wd)
 
     if cfg.shared_experts:

@@ -29,7 +29,10 @@ class Request:
 class ServeEngine:
     def __init__(self, model, params, *, max_batch: int = 4, max_len: int = 128):
         self.model = model
-        self.params = params
+        # resident in device memory for the engine's life: the jitted step
+        # takes device arrays, so no step copies parameters (a no-op for
+        # a tree ``cold_start`` already placed)
+        self.params = jax.device_put(params)
         self.B = max_batch
         self.max_len = max_len
         self.state = model.init_decode_state(max_batch, max_len)

@@ -116,6 +116,27 @@ def test_sha256_pallas_matches_hashlib(lens, seed):
     assert got == [hashlib.sha256(d).digest() for d in datas]
 
 
+def test_sha256_pallas_kernel_multi_lane_tile_multi_step():
+    """A (2 lane tiles) x (2 block steps) grid: each lane tile restarts
+    the digest state at its first step and carries it across the second,
+    with long and short lanes in both tiles."""
+    from repro.kernels.sha256.ops import (
+        digests_to_bytes,
+        pack_messages,
+        words_from_bytes,
+    )
+    from repro.kernels.sha256.sha256p import STEP_BLOCKS, sha256_lanes_pallas
+    lens = [4200, 0, 55, 64 * STEP_BLOCKS, 1, 200, 3000, 64,
+            7, 4100, 119, 64 * STEP_BLOCKS - 9, 2, 1000, 56, 4500]
+    datas = [RNG.integers(0, 256, L, dtype=np.uint8).tobytes() for L in lens]
+    buf, nb = pack_messages(datas)
+    assert buf.shape[0] == 16 and buf.shape[1] // 16 == 2 * STEP_BLOCKS
+    dig = sha256_lanes_pallas(words_from_bytes(buf), nb, interpret=True,
+                              block=8)
+    assert digests_to_bytes(dig, len(datas)) == [
+        hashlib.sha256(d).digest() for d in datas]
+
+
 # ------------------------------------------------------ the registry
 
 def test_registry_names_aliases_auto():
@@ -194,9 +215,12 @@ def test_full_restore_byte_identity_per_backend(tmp_path, backend):
     key = b"B" * 32
     blob, _ = create_image(tree, tenant="bs", tenant_key=key, store=store,
                            root=gc.active, chunk_size=4096)
+    # the tile is pinned: the autotune sweep (tested with fake backends in
+    # test_decode_stage.py) would time the Pallas interpreter for ~30 s
     svc = ImageService(store, ServiceConfig(l1_bytes=8 << 20, l2_nodes=0,
                                             fetch_concurrency=0,
-                                            max_coldstarts=0))
+                                            max_coldstarts=0,
+                                            max_batch_bytes=256 << 10))
     oracle = svc.open(blob, key).restore_tree(
         policy=ReadPolicy(mode="serial"))
     h = svc.open(blob, key)

@@ -47,6 +47,33 @@ def test_fused_boundary_lengths_match_oracles(route):
     assert fused_verify_decrypt([], []) == ([], [])
 
 
+def test_fused_kernel_multi_lane_tile_multi_step():
+    """The Pallas kernel over a (2 lane tiles) x (2 block steps) grid:
+    each lane tile restarts its digest state, carries it across steps,
+    and keys its keystream per lane, long and short lanes in both."""
+    from repro.kernels.fused.fusedp import STEP, fused_lanes_pallas
+    from repro.kernels.fused.ops import round_key_planes
+    from repro.kernels.sha256.ops import (
+        bytes_from_words,
+        digests_to_bytes,
+        pack_messages,
+        words_from_bytes,
+    )
+    cts, keys = _batch([1000, 0, 55, 64 * STEP - 9, 1, 200, 513, 64,
+                        7, 900, 119, 64 * STEP + 100, 2, 600, 56, 1013])
+    buf, nb = pack_messages(cts)
+    assert buf.shape[0] == 16 and buf.shape[1] // 16 == 2 * STEP
+    rk = round_key_planes(keys, buf.shape[0])
+    dig, plain = fused_lanes_pallas(words_from_bytes(buf), nb, rk,
+                                    rounds=rk.shape[0] - 1, interpret=True,
+                                    block=8)
+    plain = np.asarray(bytes_from_words(plain)).view(np.uint8)
+    assert digests_to_bytes(dig, len(cts)) == [
+        hashlib.sha256(ct).digest() for ct in cts]
+    for i, (ct, k) in enumerate(zip(cts, keys)):
+        assert plain[i, :len(ct)].tobytes() == aes.ctr_decrypt(ct, k), i
+
+
 def test_fused_decrypt_chunks_matches_two_pass_and_bad_positions():
     """``decrypt_chunks(fused=...)`` returns the same plaintexts as the
     default two-pass path, and on tamper raises IntegrityError with the
@@ -130,9 +157,10 @@ def test_fused_streamed_restore_poisoned_l1_evicts_and_recovers(tmp_path):
     key = b"F" * 32
     blob, _ = create_image(tree, tenant="fz", tenant_key=key, store=store,
                            root=gc.active, chunk_size=4096)
+    # pinned tile: no autotune sweep of the CPU routes (~30 s)
     svc = ImageService(store, ServiceConfig(
         l1_bytes=8 << 20, l2_nodes=0, fetch_concurrency=0, max_coldstarts=0,
-        decode_backend="bitsliced-fused"))
+        decode_backend="bitsliced-fused", max_batch_bytes=256 << 10))
     h = svc.open(blob, key)
     oracle = h.restore_tree(policy=ReadPolicy(mode="serial"))
     victim = next(c for c in h.reader.m.chunks if c.name != ZERO_CHUNK)

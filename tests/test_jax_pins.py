@@ -80,7 +80,8 @@ _PROG = textwrap.dedent("""
     assert np.allclose(oracle_over, oracle_cur, atol=1e-5), \\
         "formulations diverge even off-mesh: test is broken"
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     with use_policy(mesh, ShardingPolicy()):
         got_over = np.asarray(jax.jit(
             lambda p, x: moe_sort_overflow(p, x, cfg, jnp.float32))(p, x))

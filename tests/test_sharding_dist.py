@@ -72,15 +72,15 @@ def test_compressed_psum_matches_exact():
     stdout = _run_subprocess("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.sharding.collectives import compressed_psum
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",),
+                             axis_types=(jax.sharding.AxisType.Auto,))
         x = jax.random.normal(jax.random.key(0), (8, 1024))
 
         exact = jnp.mean(x, axis=0)
-        f = shard_map(lambda xs: compressed_psum(xs[0], "data"),
-                      mesh=mesh, in_specs=P("data", None), out_specs=P())
+        f = jax.shard_map(lambda xs: compressed_psum(xs[0], "data"),
+                          mesh=mesh, in_specs=P("data", None), out_specs=P())
         approx = f(x)
         err = float(jnp.max(jnp.abs(exact - approx)))
         rel = err / float(jnp.max(jnp.abs(exact)) + 1e-9)
@@ -106,7 +106,8 @@ def test_small_mesh_train_step_shards():
         cfg = get_config("tinyllama-1.1b").reduced(num_layers=2, d_model=64,
                                                    num_heads=4, num_kv_heads=2,
                                                    d_ff=128, vocab_size=256)
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = jax.make_mesh((2, 2), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         policy = ShardingPolicy()
         model = build_model(cfg, RunFlags())
         opt = OptConfig()
@@ -148,7 +149,8 @@ def test_sp_flash_matches_plain():
                  "labels": jax.random.randint(jax.random.key(1), (4, 32), 0,
                                               cfg.vocab_size)}
         plain = float(m.loss(params, batch))
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         pol = ShardingPolicy().with_rules("sp", seq=("model",))
         with use_policy(mesh, pol):
             sp = float(jax.jit(lambda p, b: m.loss(p, b))(params, batch))
@@ -171,7 +173,8 @@ def test_moe_shard_map_grad_matches_sort():
             capacity_factor=8.0, shared_experts=1, first_dense_layers=0)
         p, _ = moe_init(jax.random.key(0), "m", cfg)
         x = jax.random.normal(jax.random.key(1), (4, 8, 32), jnp.float32)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
         pol = ShardingPolicy()
         with use_policy(mesh, pol):
             f_sort = jax.jit(lambda p: jnp.sum(
