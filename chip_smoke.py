@@ -106,8 +106,10 @@ def run(cfg, workdir: str, *, log=print) -> dict:
     after = COUNTERS.snapshot()
     routes = route_counts({k: v - before.get(k, 0) for k, v in after.items()
                            if v != before.get(k, 0)})
-    log(f"cold start: {stats['load_seconds']:.3f}s (fetch "
-        f"{stats['fetch_wall_s']:.3f}s, decode {stats['decode_wall_s']:.3f}s)")
+    log(f"cold start: {stats['load_seconds']:.3f}s (fetch busy "
+        f"{stats['fetch_busy_s']:.3f}s, blocked on decode "
+        f"{stats['fetch_blocked_s']:.3f}s, decode "
+        f"{stats['decode_wall_s']:.3f}s)")
     log(f"decode backend: {stats['decode_backend']}; kernel routes: "
         f"{json.dumps(routes, sort_keys=True)}")
 

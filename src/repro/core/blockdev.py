@@ -90,7 +90,12 @@ from repro.core.decode import BatchDecoder
 from repro.core.layout import ranges_to_chunks
 from repro.core.manifest import ZERO_CHUNK, Manifest
 from repro.core.retry import BreakerOpenError, is_retryable
-from repro.core.telemetry import COUNTERS, LatencyRecorder
+from repro.core.telemetry import (
+    COUNTERS,
+    LatencyRecorder,
+    bind_request,
+    span,
+)
 
 PAGE = 4096
 ORIGIN_LAT_S = 36e-3          # paper: S3 origin median 36ms (simulated)
@@ -252,7 +257,8 @@ class TieredReader:
         stores), and — when a ``RetryPolicy`` is wired — backoff retries
         of transient failures. Breaker accounting only sees *retryable*
         outcomes: a ``FileNotFoundError`` is a bug, not origin weather,
-        and must not open the breaker."""
+        and must not open the breaker. One ``repro.fetch.origin`` span
+        per call (its retries included)."""
         def attempt() -> bytes:
             br = self.breaker
             if br is not None and not br.allow():
@@ -278,9 +284,11 @@ class TieredReader:
                 br.record_success()
             return ct
 
-        if self.retry is None:
-            return attempt()
-        return self.retry.call(attempt, counters=self.counters)
+        with span("repro.fetch.origin") as s:
+            ct = attempt() if self.retry is None else \
+                self.retry.call(attempt, counters=self.counters)
+            s.set_metadata(bytes=len(ct))
+        return ct
 
     def _integrity_attempts(self) -> int:
         """Total decode attempts per read: 1 (today's behavior) plus the
@@ -322,14 +330,18 @@ class TieredReader:
                 # flight transfers worker-to-worker; a miss leaves this
                 # worker leading the mesh flight — the publish/abandon
                 # below settles the lease either way
-                plat, ct = self.peer.get_chunk(ref.name, self.m.chunk_size)
+                with span("repro.fetch.peer"):
+                    plat, ct = self.peer.get_chunk(ref.name,
+                                                   self.m.chunk_size)
                 lat += plat
                 if ct is not None:
                     self.counters.inc("read.peer_hits")
                     if self.l1 is not None:
                         self.l1.put(ref.name, ct)
             if ct is None and self.l2 is not None:
-                l2lat, l2ct = self.l2.get_chunk(ref.name, self.m.chunk_size)
+                with span("repro.fetch.l2"):
+                    l2lat, l2ct = self.l2.get_chunk(ref.name,
+                                                    self.m.chunk_size)
                 lat += l2lat
                 if l2ct is not None:
                     ct, src = l2ct, "l2"
@@ -437,21 +449,26 @@ class TieredReader:
                 fb.zero_indices.append(i)
             else:
                 fb.by_name.setdefault(ref.name, []).append(i)
-        miss = []
-        for name in fb.by_name:
-            if self.l1 is not None:
-                ct = self.l1.get(name)
-                fb.l1_lat += L1_PROBE_S
-                if ct is not None:
+        miss = list(fb.by_name)
+        if self.l1 is not None:
+            probe, miss, hits = miss, [], []
+            with span("repro.fetch.l1", chunks=len(probe)) as s:
+                for name in probe:
+                    ct = self.l1.get(name)
+                    fb.l1_lat += L1_PROBE_S
+                    if ct is None:
+                        miss.append(name)
+                        continue
                     fb.ciphertexts[name] = ct
                     fb.lats[name] = L1_PROBE_S
                     fb.l1_hits += 1
                     self.counters.inc("read.l1_hits")
                     self.read_lat.record(L1_PROBE_S)
-                    if fb.sink is not None:
-                        fb.sink.put((name, ct))
-                    continue
-            miss.append(name)
+                    hits.append((name, ct))
+                s.set_metadata(bytes=sum(len(ct) for _, ct in hits))
+            if fb.sink is not None:         # pushed outside the lookup span
+                for hit in hits:
+                    fb.sink.put(hit)
         if not miss:
             return fb
         lead, follow = [], {}
@@ -549,8 +566,9 @@ class TieredReader:
                     if self.l1 is not None:
                         self.l1.put(name, ct)
                     self._resolve_flight(name, flight, ct, lat, fb)
-                pending, peer_futs = self.peer.probe_chunks(
-                    pending, self.m.chunk_size, peer_ready)
+                with span("repro.fetch.peer", chunks=len(pending)):
+                    pending, peer_futs = self.peer.probe_chunks(
+                        pending, self.m.chunk_size, peer_ready)
             if pending:
                 self._fall_through(pending, parallelism, fb, unresolved,
                                    l2_hedge)
@@ -598,12 +616,12 @@ class TieredReader:
                         self.peer.put_chunk(name, ct, source="l2")
                     self._resolve_flight(name, unresolved.pop(name),
                                          ct, lat, fb)
-                res = self.l2.get_chunks(pending, cs, on_ready=on_ready,
-                                         **l2_kw)
-            elif hasattr(self.l2, "get_chunks"):
-                res = self.l2.get_chunks(pending, cs, **l2_kw)
-            else:
-                res = {n: self.l2.get_chunk(n, cs) for n in pending}
+                l2_kw["on_ready"] = on_ready
+            with span("repro.fetch.l2", chunks=len(pending)):
+                if hasattr(self.l2, "get_chunks"):
+                    res = self.l2.get_chunks(pending, cs, **l2_kw)
+                else:
+                    res = {n: self.l2.get_chunk(n, cs) for n in pending}
             still = []
             for name in pending:
                 if name in streamed_hits:
@@ -631,6 +649,7 @@ class TieredReader:
         the serial ``_fetch_cipher``), in-flight siblings still resolve
         for their waiters, and only never-started names inherit the
         first error. Raises the first error after the stage drains."""
+        @bind_request                   # runs on the fetch pool's threads
         def fetch_origin(name: str):
             ct = self._origin_get(name)
             self.counters.inc("read.origin_fetches")
@@ -800,6 +819,9 @@ class TieredReader:
             "sim_pipelined_s": sim_wall,
             "wall_s": time.perf_counter() - t0,
             "fetch_wall_s": fetch_wall,
+            "fetch_busy_s": fetch_wall,     # staged: fetch never waits
+            "fetch_blocked_s": 0.0,
+            "decode_starved_s": 0.0,
             "decode_wall_s": decode_wall,
             "decode_backend": dec.backend,
             "streamed": False,
@@ -829,8 +851,8 @@ class TieredReader:
             else:
                 q.close()
 
-        prod = threading.Thread(target=produce, name="prefetch-fetch",
-                                daemon=True)
+        prod = threading.Thread(target=bind_request(produce),
+                                name="prefetch-fetch", daemon=True)
         prod.start()
         try:
             for _ in q:         # drain: tiers warm, nothing materializes
@@ -873,10 +895,13 @@ class TieredReader:
         (bounded rounds): the restart's good names are warm L1 hits,
         only the evicted bad names travel to origin again.
 
-        ``last_batch`` additionally reports ``overlap_s`` (decode work
-        hidden under the fetch wall), ``overlap_fraction``, and the
-        queue's high-water mark; the same figures feed the
-        ``decode.overlap_s`` / ``stream.queue_hwm`` counters."""
+        ``last_batch`` additionally reports ``fetch_busy_s`` (the fetch
+        wall less ``fetch_blocked_s``, its time blocked on the full
+        queue), ``decode_starved_s`` (the consumer's time waiting on an
+        empty queue), ``overlap_s`` (decode work that ran while fetch
+        was busy), ``overlap_fraction``, and the queue's high-water
+        mark; the same figures feed the ``decode.overlap_s`` /
+        ``stream.queue_hwm`` counters."""
         attempts = self._integrity_attempts()
         for round_ in range(attempts):
             try:
@@ -915,8 +940,8 @@ class TieredReader:
             finally:
                 holder["fetch_wall"] = time.perf_counter() - ft
 
-        prod = threading.Thread(target=produce, name="stream-fetch",
-                                daemon=True)
+        prod = threading.Thread(target=bind_request(produce),
+                                name="stream-fetch", daemon=True)
         prod.start()
         try:
             plains, dstats = dec.decrypt_stream(q, refs_by_name)
@@ -940,12 +965,15 @@ class TieredReader:
         total = time.perf_counter() - t0
         fetch_wall = holder["fetch_wall"]
         busy = dstats["busy_s"]
-        # overlap identity: decode work not in the post-fetch tail ran
-        # UNDER the fetch wall (the streaming win). `busy` sums per-tile
-        # walls across pool threads, so clamp to the fetch window —
-        # decode can never hide more than the fetch wall itself.
-        tail = max(0.0, total - fetch_wall)
-        overlap = max(0.0, min(busy - tail, fetch_wall))
+        # the producer's wall includes the time it sat on a full queue
+        # (decode behind); what is left is fetch at work
+        blocked = q.put_wait_s
+        fetch_busy = max(0.0, fetch_wall - blocked)
+        # overlap identity: at every moment fetch works, or decode does,
+        # or both (a blocked fetch waits on decode work, a starved decode
+        # on fetch work), so the work of both less the wall ran together
+        # — the streaming win. Clamped to the shorter of the two.
+        overlap = max(0.0, min(fetch_busy + busy - total, fetch_busy, busy))
         fetch_lats = [lat for lat in fb.lats.values() if lat > L1_PROBE_S]
         sim_wall = fb.l1_lat + pipelined_latency(fetch_lats, parallelism)
         self.batch_lat.record(sim_wall)
@@ -961,6 +989,9 @@ class TieredReader:
             "sim_pipelined_s": sim_wall,
             "wall_s": total,
             "fetch_wall_s": fetch_wall,
+            "fetch_busy_s": fetch_busy,
+            "fetch_blocked_s": blocked,
+            "decode_starved_s": q.get_wait_s,
             "decode_wall_s": busy,
             "decode_backend": dec.backend,
             "streamed": True,
@@ -1015,7 +1046,8 @@ class TieredReader:
         chunks = self.fetch_chunks(idxs, parallelism, streamed=streamed,
                                    queue_depth=queue_depth, decoder=decoder,
                                    l2_hedge=l2_hedge)
-        return [self._assemble(off, ln, chunks) for off, ln in ranges]
+        with span("repro.restore.assemble"):
+            return [self._assemble(off, ln, chunks) for off, ln in ranges]
 
 
 class CowBlockDevice:

@@ -13,11 +13,12 @@ the whole image in memory."""
 from __future__ import annotations
 
 import threading
+import time
 import weakref
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
 
-from repro.core.telemetry import COUNTERS
+from repro.core.telemetry import COUNTERS, span
 
 QUEUE_DONE = object()           # end-of-stream sentinel (``get``/``try_get``)
 QUEUE_EMPTY = object()          # ``try_get``: nothing queued right now
@@ -40,6 +41,10 @@ class BoundedQueue:
     * ``cancel()``: consumer gone; blocked and future puts drop.
     * ``high_water`` is the maximum depth ever reached — the concurrency
       tests assert it never exceeds ``maxsize``.
+    * ``put_wait_s`` / ``get_wait_s`` add up the time ``put`` blocked on
+      a full queue (the producer waiting on the consumer) and ``get``
+      on an empty one (the consumer waiting on the producer); each wait
+      is a ``repro.stream.put_wait`` / ``repro.stream.get_wait`` span.
 
     Iterating the queue yields items until close (StopIteration) or
     poison (raises). One producer + one consumer is the intended use;
@@ -56,11 +61,18 @@ class BoundedQueue:
         self._cancelled = False
         self._error: BaseException | None = None
         self.high_water = 0
+        self.put_wait_s = 0.0
+        self.get_wait_s = 0.0
 
     def put(self, item) -> bool:
         with self._mu:
-            while len(self._dq) >= self.maxsize and not self._cancelled:
-                self._not_full.wait()
+            if len(self._dq) >= self.maxsize and not self._cancelled:
+                t0 = time.perf_counter()
+                with span("repro.stream.put_wait"):
+                    while len(self._dq) >= self.maxsize \
+                            and not self._cancelled:
+                        self._not_full.wait()
+                self.put_wait_s += time.perf_counter() - t0
             if self._cancelled:
                 return False
             assert not self._closed, "put() after close()"
@@ -90,8 +102,12 @@ class BoundedQueue:
         """Next item; raises the poison error (drained-first) or returns
         the internal DONE sentinel once closed and empty."""
         with self._mu:
-            while not self._dq and not self._closed:
-                self._not_empty.wait()
+            if not self._dq and not self._closed:
+                t0 = time.perf_counter()
+                with span("repro.stream.get_wait"):
+                    while not self._dq and not self._closed:
+                        self._not_empty.wait()
+                self.get_wait_s += time.perf_counter() - t0
             if self._dq:
                 item = self._dq.popleft()
                 self._not_full.notify()

@@ -112,7 +112,7 @@ from dataclasses import dataclass, field
 
 from repro.core.concurrency import QUEUE_DONE, QUEUE_EMPTY, LazyPool
 from repro.core.crypto import convergent
-from repro.core.telemetry import COUNTERS
+from repro.core.telemetry import COUNTERS, bind_request, span
 
 DEFAULT_MAX_BATCH_BYTES = 256 << 10
 DEFAULT_THREADS = max(1, min(4, os.cpu_count() or 1))
@@ -485,7 +485,9 @@ class BatchDecoder:
             if len(tiles) > 1 and self.threads > 1:
                 try:
                     results = list(self._pool.get(self.threads).map(
-                        lambda t: self._decode_tile(t, ciphertexts), tiles))
+                        bind_request(
+                            lambda t: self._decode_tile(t, ciphertexts)),
+                        tiles))
                 except RuntimeError:
                     # pool shut down concurrently (service.close() racing
                     # an in-flight read): decode inline — reads through
@@ -543,8 +545,8 @@ class BatchDecoder:
                 return
             if pool is not None:
                 try:
-                    futures.append(
-                        pool.submit(self._decode_tile_timed, part, cts))
+                    futures.append(pool.submit(
+                        bind_request(self._decode_tile_timed), part, cts))
                 except RuntimeError:
                     # pool shut down concurrently (service.close()
                     # racing this stream): fall back to inline decode
@@ -664,7 +666,9 @@ class BatchDecoder:
         if len(tiles) > 1 and self.threads > 1:
             try:
                 results = list(self._pool.get(self.threads).map(
-                    lambda t: self._forward_tile(t[0], salt, t[1]), tiles))
+                    bind_request(
+                        lambda t: self._forward_tile(t[0], salt, t[1])),
+                    tiles))
             except RuntimeError:        # pool shut down concurrently
                 results = [self._forward_tile(p, salt, k) for p, k in tiles]
         else:
@@ -710,12 +714,14 @@ class BatchDecoder:
         ({name: plaintext}, [tampered names])."""
         cts = [ciphertexts[r.name] for r in part]
         try:
-            plains = convergent.decrypt_chunks(
-                cts, [r.key for r in part], [r.sha256 for r in part],
-                sha_backend=self.sha_backend,
-                encrypt_many=self._encrypt_many,
-                sha_many=self._sha_many,
-                fused=self._fused)
+            with span("repro.decode.tile", chunks=len(cts),
+                      bytes=sum(map(len, cts))):
+                plains = convergent.decrypt_chunks(
+                    cts, [r.key for r in part], [r.sha256 for r in part],
+                    sha_backend=self.sha_backend,
+                    encrypt_many=self._encrypt_many,
+                    sha_many=self._sha_many,
+                    fused=self._fused)
         except convergent.IntegrityError as e:
             return {}, [part[i].name for i in e.bad_positions]
         return {r.name: p for r, p in zip(part, plains)}, []

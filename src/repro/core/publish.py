@@ -35,6 +35,11 @@ root, the §3.4 collection input) when one is attached, and warms the
 L1 / peer tiers with the freshly-uploaded ciphertexts so the first
 cold-start of a just-published checkpoint hits locally.
 
+Each publish is one ``request_scope`` with a ``repro.publish`` span; its
+stages are ``repro.publish.chunk``, ``.derive_keys``, ``.probe``,
+``.encrypt``, ``.upload`` (one per upload group, on the upload threads)
+and ``.seal``, each span carrying the publish's ``request``.
+
 ``GenerationalGC.migrate`` reuses the same machinery via
 ``copy_chunks`` (batched presence probe + bounded-parallel
 single-flighted copies).
@@ -60,7 +65,7 @@ from repro.core.layout import (
     canonical_paths,
 )
 from repro.core.manifest import ZERO_CHUNK, ChunkRef, Manifest, seal
-from repro.core.telemetry import COUNTERS
+from repro.core.telemetry import COUNTERS, bind_request, request_scope, span
 
 DEFAULT_UPLOAD_PARALLELISM = 8
 # stage batches this large keep every vectorized pass amortized even
@@ -261,28 +266,41 @@ class PublishPipeline:
         same stats semantics)."""
         t0 = time.perf_counter()
         lay = build_layout(tree, chunk_size)
+        with request_scope(), span("repro.publish",
+                                   image_bytes=lay.image_size):
+            blob, stats = self._publish(lay, tree, tenant, tenant_key, root,
+                                        salt_epoch, image_id, chunk_size)
+        self.counters.inc("publish.images_published")
+        self.counters.add("publish.wall_s", time.perf_counter() - t0)
+        return blob, stats
+
+    def _publish(self, lay, tree, tenant, tenant_key, root, salt_epoch,
+                 image_id, chunk_size) -> tuple:
         items = canonical_paths(tree)
         salt = convergent.make_salt(salt_epoch, root)
         image_id = image_id or image_id_for(tree)
         refs: dict[int, ChunkRef] = {}
         futures: list = []
         zero = probe_dedup = 0
-        batch: list = []
-        batch_bytes = 0
-        for idx, chunk in StreamingImageWriter(lay).chunks(items):
-            # C-speed zero scan (same predicate as the oracle's np.any,
-            # without per-chunk numpy dispatch)
-            if chunk.count(0) == len(chunk):
-                refs[idx] = ChunkRef(idx, ZERO_CHUNK)
-                zero += 1
-                continue
-            batch.append((idx, chunk))
-            batch_bytes += len(chunk)
-            if batch_bytes >= self.stage_bytes:
-                probe_dedup += self._publish_batch(batch, salt, root, refs,
-                                                   futures)
-                batch, batch_bytes = [], 0
-        if batch:
+        chunks = iter(StreamingImageWriter(lay).chunks(items))
+        while True:
+            batch: list = []
+            batch_bytes = 0
+            with span("repro.publish.chunk") as s:
+                for idx, chunk in chunks:
+                    # C-speed zero scan (same predicate as the oracle's
+                    # np.any, without per-chunk numpy dispatch)
+                    if chunk.count(0) == len(chunk):
+                        refs[idx] = ChunkRef(idx, ZERO_CHUNK)
+                        zero += 1
+                        continue
+                    batch.append((idx, chunk))
+                    batch_bytes += len(chunk)
+                    if batch_bytes >= self.stage_bytes:
+                        break
+                s.set_metadata(chunks=len(batch), bytes=batch_bytes)
+            if not batch:
+                break
             probe_dedup += self._publish_batch(batch, salt, root, refs,
                                                futures)
         unique = uploaded = upload_dedup = 0
@@ -291,24 +309,24 @@ class PublishPipeline:
             unique += nnew
             upload_dedup += ndup
             uploaded += nbytes
-        chunks = [refs[i] for i in sorted(refs)]
-        m = Manifest(image_id=image_id, tenant=tenant, root_id=root,
-                     salt=salt, chunk_size=chunk_size,
-                     image_size=lay.image_size,
-                     layout_table=lay.to_table(), chunks=chunks)
-        blob = seal(m, tenant_key)
-        self.store.put_manifest(root, image_id, blob)
-        if self.refcounts is not None:
-            self.refcounts.add_image(
-                root, image_id,
-                [c.name for c in chunks if c.name != ZERO_CHUNK])
-        stats = CreateStats(image_id, len(chunks), zero, unique,
+        with span("repro.publish.seal"):
+            ordered = [refs[i] for i in sorted(refs)]
+            m = Manifest(image_id=image_id, tenant=tenant, root_id=root,
+                         salt=salt, chunk_size=chunk_size,
+                         image_size=lay.image_size,
+                         layout_table=lay.to_table(), chunks=ordered)
+            blob = seal(m, tenant_key)
+            self.store.put_manifest(root, image_id, blob)
+            if self.refcounts is not None:
+                self.refcounts.add_image(
+                    root, image_id,
+                    [c.name for c in ordered if c.name != ZERO_CHUNK])
+            self.names.save()   # persist skip-encryption dedup (no-op
+                                # without a sidecar path)
+        stats = CreateStats(image_id, len(ordered), zero, unique,
                             probe_dedup + upload_dedup, lay.image_size,
                             uploaded)
-        self.counters.inc("publish.images_published")
-        self.counters.add("publish.wall_s", time.perf_counter() - t0)
-        self.names.save()        # persist skip-encryption dedup (no-op
-        return blob, stats       # without a sidecar path)
+        return blob, stats
 
     def _publish_batch(self, batch: list, salt: bytes, root: str,
                        refs: dict, futures: list) -> int:
@@ -320,12 +338,16 @@ class PublishPipeline:
         encryption of the next batch overlaps these uploads)."""
         idxs = [i for i, _ in batch]
         pts = [c for _, c in batch]
-        keys = self.decoder.derive_keys_batch(pts, salt)
-        names = self.names.get_many(keys)
-        known = [p for p, n in enumerate(names) if n is not None]
-        present: set = set()
-        if known:
-            present = self.store.has_chunks(root, [names[p] for p in known])
+        with span("repro.publish.derive_keys", chunks=len(pts),
+                  bytes=sum(map(len, pts))):
+            keys = self.decoder.derive_keys_batch(pts, salt)
+        with span("repro.publish.probe", chunks=len(pts)):
+            names = self.names.get_many(keys)
+            known = [p for p, n in enumerate(names) if n is not None]
+            present: set = set()
+            if known:
+                present = self.store.has_chunks(root,
+                                                [names[p] for p in known])
         skipped = 0
         skipped_bytes = 0
         to_encrypt: list[int] = []
@@ -342,9 +364,11 @@ class PublishPipeline:
             self.counters.add("publish.encrypt_skipped_bytes", skipped_bytes)
         if not to_encrypt:
             return skipped
-        encs, _wall = self.decoder.encrypt_batch_timed(
-            [pts[p] for p in to_encrypt], salt,
-            keys=[keys[p] for p in to_encrypt])
+        with span("repro.publish.encrypt", chunks=len(to_encrypt),
+                  bytes=sum(len(pts[p]) for p in to_encrypt)):
+            encs, _wall = self.decoder.encrypt_batch_timed(
+                [pts[p] for p in to_encrypt], salt,
+                keys=[keys[p] for p in to_encrypt])
         self.names.put_many((e.key, e.name) for e in encs)
         for p, enc in zip(to_encrypt, encs):
             refs[idxs[p]] = ChunkRef(idxs[p], enc.name, enc.key, enc.sha256)
@@ -369,7 +393,7 @@ class PublishPipeline:
         self._limiter.acquire()
         try:
             fut = self._pool.get(self.upload_parallelism).submit(
-                self._upload_group, root, items)
+                bind_request(self._upload_group), root, items)
         except BaseException:
             self._limiter.release()
             raise
@@ -380,12 +404,14 @@ class PublishPipeline:
         single-flighted PUT-if-absent uploads."""
         new = dup = nbytes = 0
         try:
-            for name, ct in items:
-                if self._upload_one(root, name, ct):
-                    new += 1
-                    nbytes += len(ct)
-                else:
-                    dup += 1
+            with span("repro.publish.upload", chunks=len(items),
+                      bytes=sum(len(ct) for _, ct in items)):
+                for name, ct in items:
+                    if self._upload_one(root, name, ct):
+                        new += 1
+                        nbytes += len(ct)
+                    else:
+                        dup += 1
             return new, dup, nbytes
         finally:
             self._limiter.release()

@@ -73,7 +73,7 @@ from repro.core.layout import (
 from repro.core.manifest import open_manifest
 from repro.core.publish import PublishPipeline
 from repro.core.retry import CircuitBreaker, RetryPolicy
-from repro.core.telemetry import COUNTERS, ScopedCounters
+from repro.core.telemetry import COUNTERS, ScopedCounters, span
 
 _MODES = ("streamed", "staged", "serial")
 
@@ -622,9 +622,14 @@ class ImageHandle:
     def restore_tree(self, names=None,
                      policy: ReadPolicy | None = None) -> dict:
         """Flat {path: array} for all (or selected) tensors, via one
-        pipelined batch shaped by `policy` (service default: streamed)."""
+        pipelined batch shaped by `policy` (service default: streamed);
+        one ``repro.restore`` span."""
         names = names if names is not None else self.tensor_names()
-        return self.restore_shards({n: None for n in names}, policy)
+        tensors = [self.layout.tensors[n] for n in names]
+        chunks = ranges_to_chunks([(t.offset, t.nbytes) for t in tensors],
+                                  self.manifest.chunk_size)
+        with span("repro.restore", chunks=len(chunks)):
+            return self.restore_shards({n: None for n in names}, policy)
 
     def restore_shards(self, shard_slices: dict,
                        policy: ReadPolicy | None = None) -> dict:
@@ -657,11 +662,12 @@ class ImageHandle:
                 queue_depth=p.queue_depth, decoder=dec,
                 l2_hedge=p.l2_hedge))
         out = {}
-        for name, ranges, shape, dt in plan:
-            raw = b"".join(next(bufs) for _ in ranges)
-            # reshape(()) yields a 0-d array for scalars — identical to
-            # the serial read_tensor path
-            out[name] = np.frombuffer(raw, dt).reshape(shape)
+        with span("repro.restore.assemble"):
+            for name, ranges, shape, dt in plan:
+                raw = b"".join(next(bufs) for _ in ranges)
+                # reshape(()) yields a 0-d array for scalars — identical
+                # to the serial read_tensor path
+                out[name] = np.frombuffer(raw, dt).reshape(shape)
         return out
 
     def tensor_shard(self, name: str, dim_slices: list,

@@ -1,10 +1,93 @@
-"""Counters and latency recorders feeding the paper-figure benchmarks."""
+"""Counters and latency recorders feeding the paper-figure benchmarks,
+and the loader's profiler spans.
+
+``span(name, **attrs)`` is a ``jax.profiler.TraceAnnotation``: under a
+profiler capture it lands on the host plane, on the clock the device
+events use, with `attrs` as the event's stats; without a capture it is
+a shared do-nothing context, cheaper than a ``TraceAnnotation`` (a span
+opened before a capture starts is not in it). Every span opened inside
+``request_scope()`` carries that scope's ``request`` id. Context
+variables do not cross into threads, so work handed to a producer
+thread or a pool goes through ``bind_request``, which carries the id
+over explicitly."""
 from __future__ import annotations
 
+import contextlib
+import contextvars
+import itertools
+import sys
 import threading
 from collections import defaultdict, deque
 
 import numpy as np
+
+_REQUEST: contextvars.ContextVar = contextvars.ContextVar(
+    "repro_request", default=None)
+_REQUEST_IDS = itertools.count(1)
+_ANNOTATION = None          # jax.profiler.TraceAnnotation, once jax is loaded
+
+
+class _NoSpan:
+    """What ``span`` returns while no profiler is capturing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **attrs):
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def span(name: str, **attrs):
+    """A profiler span named `name` with `attrs` as its stats (a context
+    manager; ``set_metadata(**attrs)`` adds stats known only inside)."""
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        if "jax" not in sys.modules:    # no capture without jax
+            return _NO_SPAN
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    if not _ANNOTATION.is_enabled():
+        return _NO_SPAN
+    request = _REQUEST.get()
+    if request is not None:
+        attrs.setdefault("request", request)
+    return _ANNOTATION(name, **attrs)
+
+
+@contextlib.contextmanager
+def request_scope():
+    """A new request id for the spans this thread opens inside; yields
+    the id."""
+    rid = next(_REQUEST_IDS)
+    token = _REQUEST.set(rid)
+    try:
+        yield rid
+    finally:
+        _REQUEST.reset(token)
+
+
+def bind_request(fn):
+    """`fn`, run under the calling thread's request id wherever it runs
+    (a producer thread, a pool worker)."""
+    rid = _REQUEST.get()
+    if rid is None:
+        return fn
+
+    def run(*args, **kwargs):
+        token = _REQUEST.set(rid)
+        try:
+            return fn(*args, **kwargs)
+        finally:
+            _REQUEST.reset(token)
+    return run
 
 
 class Counters:
@@ -89,6 +172,8 @@ class ScopedCounters:
 
 
 COUNTERS = Counters()
+H2D_BYTES = "xfer.h2d_bytes"    # bytes copied host -> device by the loader
+D2H_BYTES = "xfer.d2h_bytes"    # bytes copied device -> host by the loader
 
 
 class QuantileWindow:

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.telemetry import COUNTERS, D2H_BYTES, H2D_BYTES, span
 from repro.kernels import pallas_interpret
 from repro.kernels.aes import aesjax, bitslice
 
@@ -98,6 +99,27 @@ def encrypt_many_bitsliced(blocks_u8: np.ndarray, rks: np.ndarray, *,
     if n == 0:
         return np.empty((0, 16), np.uint8)
     interpret = pallas_interpret("aes", interpret)
+    with span("repro.kernel.pack"):
+        blocks_u8, rks, idx = _pack_bitsliced(blocks_u8, rks, counts)
+    h2d = blocks_u8.nbytes + rks.nbytes + idx.nbytes
+    with span("repro.kernel.dispatch", h2d_bytes=h2d):
+        out = _encrypt_device(blocks_u8, rks, idx, rounds=rks.shape[1] - 1,
+                              interpret=interpret)
+    COUNTERS.add(H2D_BYTES, h2d)
+    with span("repro.kernel.readback", d2h_bytes=out.nbytes):
+        host = np.asarray(out)
+    COUNTERS.add(D2H_BYTES, out.nbytes)
+    return host[:n]
+
+
+encrypt_many_bitsliced.per_chunk_rks = True
+
+
+def _pack_bitsliced(blocks_u8: np.ndarray, rks: np.ndarray,
+                    counts: np.ndarray | None) -> tuple:
+    """(blocks, per-chunk schedules, per-block chunk index), padded to
+    ``encrypt_many_bitsliced``'s buckets."""
+    n = blocks_u8.shape[0]
     if counts is None:
         if rks.ndim == 2:
             rks = rks[None]
@@ -122,13 +144,7 @@ def encrypt_many_bitsliced(blocks_u8: np.ndarray, rks: np.ndarray, *,
         cb <<= 1
     if cb > c:
         rks = np.concatenate([rks, np.repeat(rks[-1:], cb - c, axis=0)])
-    rounds = rks.shape[1] - 1
-    out = _encrypt_device(blocks_u8, rks, idx, rounds=rounds,
-                          interpret=interpret)
-    return np.asarray(out)[:n]
-
-
-encrypt_many_bitsliced.per_chunk_rks = True
+    return blocks_u8, rks, idx
 
 
 def ctr_keystream_many_bitsliced(keys: list, nbytes: list,

@@ -11,6 +11,8 @@ detection and the eviction/retry semantics are unchanged.
 Marshalling is the SHA kernel's (``sha256.ops.pack_messages``): one
 padded byte row per chunk, word swap and transpose on the device. The
 round keys travel as per-chunk 0/-1 bit planes (``round_key_planes``).
+Each call is four ``repro.kernel.*`` spans (pack, dispatch, readback,
+split) and adds its copies to the ``xfer.*`` counters.
 """
 from __future__ import annotations
 
@@ -20,6 +22,7 @@ import jax
 import numpy as np
 
 from repro.core.crypto.aes import expand_key
+from repro.core.telemetry import COUNTERS, D2H_BYTES, H2D_BYTES, span
 from repro.kernels import on_tpu, pallas_interpret, record_route
 from repro.kernels.fused.fusedp import fused_lanes_jit, fused_lanes_pallas
 from repro.kernels.sha256.ops import (
@@ -75,10 +78,19 @@ def fused_verify_decrypt(cts: list, keys: list, *,
     else:
         interpret = False
         record_route("fused", "xla-jit")
-    buf, nb = pack_messages(cts)
-    rk = round_key_planes(keys, buf.shape[0])
-    dig, plain = _fused_device(buf, nb, rk, rounds=rk.shape[0] - 1,
-                               pallas=pallas, interpret=interpret)
-    plain = np.asarray(plain).view(np.uint8)
-    return (digests_to_bytes(dig, n),
-            [plain[i, :len(ct)].tobytes() for i, ct in enumerate(cts)])
+    with span("repro.kernel.pack"):
+        buf, nb = pack_messages(cts)
+        rk = round_key_planes(keys, buf.shape[0])
+    h2d = buf.nbytes + nb.nbytes + rk.nbytes
+    with span("repro.kernel.dispatch", h2d_bytes=h2d):
+        dig, plain = _fused_device(buf, nb, rk, rounds=rk.shape[0] - 1,
+                                   pallas=pallas, interpret=interpret)
+    COUNTERS.add(H2D_BYTES, h2d)
+    d2h = dig.nbytes + plain.nbytes
+    with span("repro.kernel.readback", d2h_bytes=d2h):
+        dig = np.asarray(dig)
+        plain = np.asarray(plain).view(np.uint8)
+    COUNTERS.add(D2H_BYTES, d2h)
+    with span("repro.kernel.split"):
+        return (digests_to_bytes(dig, n),
+                [plain[i, :len(ct)].tobytes() for i, ct in enumerate(cts)])

@@ -9,6 +9,8 @@ into a zeroed (lanes, maxb*64) byte buffer plus its SHA padding
 kernel's word-major layout run on the device (``words_from_bytes``).
 Batch dimensions are bucketed (lanes to powers of two, message blocks
 to coarse steps) so the kernel retraces O(log) times, not per shape.
+Each call is four ``repro.kernel.*`` spans (pack, dispatch, readback,
+split) and adds its copies to the ``xfer.*`` counters.
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ import functools
 import jax
 import numpy as np
 
+from repro.core.telemetry import COUNTERS, D2H_BYTES, H2D_BYTES, span
 from repro.kernels import pallas_interpret
 from repro.kernels.sha256.sha256p import STEP_BLOCKS, sha256_lanes_pallas
 
@@ -104,5 +107,14 @@ def sha256_many_pallas(datas: list, *, interpret: bool | None = None) -> list:
     if n == 0:
         return []
     interpret = pallas_interpret("sha256", interpret)
-    buf, nb = pack_messages(datas)
-    return digests_to_bytes(_sha_device(buf, nb, interpret=interpret), n)
+    with span("repro.kernel.pack"):
+        buf, nb = pack_messages(datas)
+    h2d = buf.nbytes + nb.nbytes
+    with span("repro.kernel.dispatch", h2d_bytes=h2d):
+        dig = _sha_device(buf, nb, interpret=interpret)
+    COUNTERS.add(H2D_BYTES, h2d)
+    with span("repro.kernel.readback", d2h_bytes=dig.nbytes):
+        dig_host = np.asarray(dig)
+    COUNTERS.add(D2H_BYTES, dig.nbytes)
+    with span("repro.kernel.split"):
+        return digests_to_bytes(dig_host, n)

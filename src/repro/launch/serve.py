@@ -294,10 +294,12 @@ def main():
     engine, stats = cold_start(model, blob, key, service, policy=policy,
                                max_batch=4, max_len=64)
     pipe = ""
-    if stats.get("fetch_wall_s") is not None:   # serial mode has no split
-        pipe = (f", fetch {stats['fetch_wall_s']:.2f}s + "
+    if stats.get("fetch_busy_s") is not None:   # serial mode has no split
+        pipe = (f", fetch {stats['fetch_busy_s']:.2f}s busy "
+                f"+ {stats['fetch_blocked_s']:.2f}s blocked on decode, "
                 f"decode[{stats['decode_backend']}] "
-                f"{stats['decode_wall_s']:.2f}s")
+                f"{stats['decode_wall_s']:.2f}s "
+                f"+ {stats['decode_starved_s']:.2f}s starved")
     if stats.get("streamed"):
         pipe += (f", {stats['overlap_s']:.2f}s decode hidden under fetch "
                  f"(queue hwm {stats['queue_hwm']}"

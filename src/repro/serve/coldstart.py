@@ -12,6 +12,10 @@ memory, and stands up a ``ServeEngine`` over it. For MoE configs,
 sparsity: the demand-loading analogue of 'applications touch 6.4% of
 the image').
 
+Each cold start is one ``request_scope``: its ``repro.coldstart`` span
+holds ``.admit``, ``.open``, ``repro.restore`` and ``.place``, and
+every span of the start, on whichever thread, carries its ``request``.
+
 The pre-redesign calling convention — a raw store plus the
 l1/l2/limiter/fetch_limiter/batched/streamed/parallelism knob tuple —
 still works as a deprecation path: it builds a private single-image
@@ -20,6 +24,7 @@ service per call. New code passes an ``ImageService`` and a
 """
 from __future__ import annotations
 
+import contextlib
 import time
 
 import jax
@@ -27,6 +32,7 @@ import numpy as np
 
 from repro.core.blockdev import DEFAULT_PARALLELISM
 from repro.core.service import ImageService, ReadPolicy, single_image_service
+from repro.core.telemetry import COUNTERS, H2D_BYTES, request_scope, span
 from repro.serve.engine import ServeEngine
 from repro.train.checkpoint import tree_from_flat
 
@@ -92,10 +98,17 @@ def cold_start(model, manifest_blob: bytes, tenant_key: bytes, service, *,
 
 def _cold_start_admitted(model, manifest_blob, tenant_key, service, root,
                          tenant, policy, max_batch, max_len, decoder):
-    with service.admission_slot():
+    with contextlib.ExitStack() as stack:
+        stack.enter_context(request_scope())
+        top = stack.enter_context(span("repro.coldstart"))
+        with span("repro.coldstart.admit"):
+            stack.enter_context(service.admission_slot())
         t0 = time.time()
-        handle = service.open(manifest_blob, tenant_key, root=root,
-                              tenant=tenant, decoder=decoder)
+        with span("repro.coldstart.open"):
+            handle = service.open(manifest_blob, tenant_key, root=root,
+                                  tenant=tenant, decoder=decoder)
+        top.set_metadata(image_bytes=handle.layout.image_size,
+                         tile_bytes=handle._resolve(policy)[1].max_batch_bytes)
         # origin traffic is attributed through the tenant's telemetry
         # scope, not the global counter — concurrent cold-starts of
         # OTHER tenants through the same service must not leak into
@@ -103,13 +116,18 @@ def _cold_start_admitted(model, manifest_blob, tenant_key, service, root,
         before_origin = handle.counters.get("read.origin_fetches")
         template = model.param_shapes()
         flat = handle.restore_tree(policy=policy)
-        params = tree_from_flat(template, flat)
-        # promote on the host, then place the tree in device memory once:
-        # the load clock stops when the weights sit there, not on the host
-        params = jax.device_put(jax.tree.map(
-            lambda p: p.astype(np.float32) if p.dtype == np.float64 else p,
-            params))
-        jax.block_until_ready(params)
+        with span("repro.restore.assemble"):
+            # promoted on the host, so the device holds the serving dtype
+            params = jax.tree.map(
+                lambda p: p.astype(np.float32) if p.dtype == np.float64
+                else p, tree_from_flat(template, flat))
+        # place the tree in device memory once: the load clock stops when
+        # the weights sit there, not on the host
+        h2d = sum(p.nbytes for p in jax.tree.leaves(params))
+        with span("repro.coldstart.place", h2d_bytes=h2d):
+            params = jax.device_put(params)
+            jax.block_until_ready(params)
+        COUNTERS.add(H2D_BYTES, h2d)
         t_load = time.time() - t0
         engine = ServeEngine(model, params, max_batch=max_batch, max_len=max_len)
         # last_batch is the shared reader's most recent batch: exact for
@@ -122,12 +140,12 @@ def _cold_start_admitted(model, manifest_blob, tenant_key, service, root,
             "origin_fetches": handle.counters.get("read.origin_fetches")
             - before_origin,
             "image_bytes": handle.layout.image_size,
-            "l2_sim_latency_p50": handle.reader.read_lat.percentile(50),
-            "sim_pipelined_s": lb.get("sim_pipelined_s"),
-            "sim_serial_s": lb.get("sim_serial_s"),
-            # pipeline split: I/O wall vs decode work; in streamed mode
-            # overlap_s is the decode work hidden under the fetch wall
-            "fetch_wall_s": lb.get("fetch_wall_s"),
+            # pipeline split: fetch at work, fetch blocked on the decode
+            # queue, decode work and decode waiting on fetch; in streamed
+            # mode overlap_s is the decode work done while fetch was busy
+            "fetch_busy_s": lb.get("fetch_busy_s"),
+            "fetch_blocked_s": lb.get("fetch_blocked_s"),
+            "decode_starved_s": lb.get("decode_starved_s"),
             "decode_wall_s": lb.get("decode_wall_s"),
             "decode_backend": lb.get("decode_backend"),
             "streamed": lb.get("streamed"),

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.telemetry import COUNTERS
+from repro.core.telemetry import COUNTERS, span
 
 
 @dataclass
@@ -63,10 +63,11 @@ class ServeEngine:
         active = [s for s in range(self.B) if self.slot_req[s] is not None]
         if not active:
             return False
-        logits, self.state = self._decode(
-            self.params, self.state, jnp.asarray(self.tokens),
-            jnp.asarray(self.pos))
-        logits = np.asarray(logits)
+        with span("repro.serve.step", first=self.steps == 0):
+            logits, self.state = self._decode(
+                self.params, self.state, jnp.asarray(self.tokens),
+                jnp.asarray(self.pos))
+            logits = np.asarray(logits)
         self.steps += 1
         for s in active:
             req = self.slot_req[s]
