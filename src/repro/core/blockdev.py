@@ -1000,6 +1000,7 @@ class TieredReader:
             "queue_hwm": q.high_water,
             "queue_depth": q.maxsize,
             "decode_tiles": dstats["tiles"],
+            "tiles_overlapped": dstats["tiles_overlapped"],
             "eager_flushes": dstats.get("eager_flushes", 0),
             "eager_holds": dstats.get("eager_holds", 0),
         }

@@ -128,25 +128,26 @@ def decrypt_chunks(ciphertexts: list, keys: list, expect_sha256s: list, *,
     either way)."""
     if fused is not None:
         digests, plains = fused(list(ciphertexts), list(keys))
-        bad = [i for i, (got, want)
-               in enumerate(zip(digests, expect_sha256s)) if got != want]
-        if bad:
-            raise IntegrityError(
-                f"chunk ciphertext hash mismatch at batch positions {bad}",
-                bad)
+        check_digests(digests, expect_sha256s)
         return plains
     if sha_many is not None:
         digests = sha_many(list(ciphertexts))
     else:
         digests = sha256_many(list(ciphertexts), backend=sha_backend)
+    check_digests(digests, expect_sha256s)
+    return aes.ctr_decrypt_many(list(ciphertexts), list(keys),
+                                encrypt_many=encrypt_many)
+
+
+def check_digests(digests: list, expect_sha256s: list) -> None:
+    """Raise ``IntegrityError`` naming every batch position whose digest
+    differs from the expected one."""
     bad = [i for i, (got, want) in enumerate(zip(digests, expect_sha256s))
            if got != want]
     if bad:
         raise IntegrityError(
             f"chunk ciphertext hash mismatch at batch positions {bad}",
             bad)
-    return aes.ctr_decrypt_many(list(ciphertexts), list(keys),
-                                encrypt_many=encrypt_many)
 
 
 class IntegrityError(Exception):

@@ -16,16 +16,19 @@ Two consumption modes:
   producing. Chunks accumulate into ``max_batch_bytes`` tiles with
   exactly the ``_split`` invariants (a tile never exceeds the cap unless
   a single chunk does; arrival order is preserved within the stream) and
-  each full tile is dispatched to the GIL-releasing pool the moment it
-  fills — so decode wall-clock hides behind the deepest fetch miss
-  instead of starting after it. The streaming contract:
+  each full tile is dispatched the moment it fills — to the
+  GIL-releasing pool, or, for a kernel backend, to the consumer's own
+  tile loop, which keeps the next tile in flight behind the current
+  one's kernel (``_TileLoop``) — so decode wall-clock hides behind the
+  deepest fetch miss instead of starting after it. The streaming
+  contract:
 
   - the stream is drained even after a bad tile, and the final
     ``IntegrityError`` names EVERY bad chunk across all tiles, in sorted
     (deterministic) order — never a partial report;
   - no plaintext of a bad chunk is ever returned;
   - a fetch-side failure (queue poisoned) is re-raised only after all
-    dispatched tiles finish, so no decode worker is left running.
+    dispatched tiles finish, so no decode work is left running.
 
   With ``eager_flush`` (gated by ``ReadPolicy.eager_flush``) the
   consumer additionally dispatches its PARTIAL tile whenever it would
@@ -108,6 +111,7 @@ import os
 import threading
 import time
 import warnings
+from collections import deque
 from dataclasses import dataclass, field
 
 from repro.core.concurrency import QUEUE_DONE, QUEUE_EMPTY, LazyPool
@@ -117,6 +121,10 @@ from repro.core.telemetry import COUNTERS, bind_request, span
 DEFAULT_MAX_BATCH_BYTES = 256 << 10
 DEFAULT_THREADS = max(1, min(4, os.cpu_count() or 1))
 DEFAULT_EAGER_MIN_BYTES = 32 << 10
+# tiles in flight at most on a two-phase kernel hook: with one tile's
+# host work (pack, dispatch, readback, split, digest check) shorter than
+# the other's kernel, two keep the device busy
+PIPELINE_DEPTH = 2
 
 
 # ------------------------------------------------------------- registry
@@ -133,7 +141,9 @@ class DecodeBackend:
     string path / the two-pass route respectively. A ``fused`` hook is
     ``(ciphertexts, keys) -> (digests, plaintexts)`` in one pass —
     ``convergent.decrypt_chunks`` compares the digests before releasing
-    plaintext, so tamper semantics are hook-independent.
+    plaintext, so tamper semantics are hook-independent. A hook that
+    also offers ``submit`` (launch now, ``result()`` later) gets its
+    tiles pipelined (``_TileLoop``).
     ``threads=None`` leaves tile threading to the decoder default;
     ``1`` means the kernel owns its parallelism (XLA / Pallas)."""
 
@@ -482,6 +492,7 @@ class BatchDecoder:
                     bad_names.append(ref.name)
         else:
             tiles = list(self._split(refs, ciphertexts))
+            results = None
             if len(tiles) > 1 and self.threads > 1:
                 try:
                     results = list(self._pool.get(self.threads).map(
@@ -492,10 +503,9 @@ class BatchDecoder:
                     # pool shut down concurrently (service.close() racing
                     # an in-flight read): decode inline — reads through
                     # live handles must keep working
-                    results = [self._decode_tile(t, ciphertexts)
-                               for t in tiles]
-            else:
-                results = [self._decode_tile(t, ciphertexts) for t in tiles]
+                    pass
+            if results is None:
+                results = _TileLoop(self).run(tiles, ciphertexts)
             for plains, bad in results:
                 out.update(plains)
                 bad_names.extend(bad)
@@ -510,12 +520,15 @@ class BatchDecoder:
         """Streaming consumer: drain ``(name, ciphertext)`` pairs from a
         ``BoundedQueue`` (see module docstring for the contract),
         accumulating ``max_batch_bytes`` tiles and dispatching each to
-        the pool while the fetch producer is still running.
+        the pool — or, single-threaded, to this thread's tile loop
+        (``_TileLoop``) — while the fetch producer is still running.
 
         ``refs_by_name`` maps chunk name -> ChunkRef (key + expected
         sha256). Returns ``({name: plaintext}, stats)`` where stats has
-        ``busy_s`` (summed decode work time, the overlap-accounting
-        input), ``wall_s`` (consumer elapsed) and ``tiles``.
+        ``busy_s`` (the time at least one tile was in flight, tiles
+        that overlap counted once: the overlap-accounting input),
+        ``wall_s`` (consumer elapsed), ``tiles`` and ``tiles_overlapped``
+        (tiles submitted while an earlier one was still in flight).
 
         A poisoned queue (fetch failure) re-raises the producer's error
         after all dispatched tiles complete; tampered chunks raise one
@@ -528,13 +541,14 @@ class BatchDecoder:
         out: dict[str, bytes] = {}
         bad_names: list[str] = []
         results: list = []
+        intervals: list = []
         futures: list = []
         pool = self._pool.get(self.threads) \
             if self.backend != "serial" and self.threads > 1 else None
+        loop = _TileLoop(self)
         part: list = []
         cts: dict[str, bytes] = {}
         size = 0
-        busy_inline = 0.0
         eager = self.eager_flush and self.backend != "serial"
         eager_flushes = 0
         eager_holds = 0
@@ -550,9 +564,9 @@ class BatchDecoder:
                 except RuntimeError:
                     # pool shut down concurrently (service.close()
                     # racing this stream): fall back to inline decode
-                    results.append(self._decode_tile_timed(part, cts))
+                    loop.push(part, cts)
             else:
-                results.append(self._decode_tile_timed(part, cts))
+                loop.push(part, cts)
             part, cts, size = [], {}, 0
 
         stream_err = None
@@ -563,7 +577,7 @@ class BatchDecoder:
                     if item is QUEUE_EMPTY:
                         # the consumer would block here. Flush the
                         # partial tile only if decode capacity is
-                        # actually idle — when tiles are still in
+                        # actually idle — when pool tiles are still in
                         # flight, an early flush just shreds tile
                         # efficiency without starting any work sooner —
                         # AND the partial has accumulated at least
@@ -571,11 +585,15 @@ class BatchDecoder:
                         # trades the whole tile-batching win for a
                         # negligible head start (the threshold is the
                         # ROADMAP item-2 trigger, tuned via
-                        # benchmarks/e2e_read_latency.py).
+                        # benchmarks/e2e_read_latency.py). Tiles in
+                        # flight on this thread's loop are finished
+                        # first, while the queue is empty anyway, so the
+                        # flush fires with no tile in flight.
                         if size < self.eager_min_bytes:
                             eager_holds += 1
                             COUNTERS.inc("decode.eager_holds")
-                        elif pool is None or all(f.done() for f in futures):
+                        elif all(f.done() for f in futures):
+                            loop.drain()
                             flush()
                             eager_flushes += 1
                             COUNTERS.inc("decode.eager_flushes")
@@ -593,31 +611,37 @@ class BatchDecoder:
                             ct, ref.key, ref.sha256)
                     except convergent.IntegrityError:
                         bad_names.append(ref.name)
-                    busy_inline += time.perf_counter() - ts
+                    intervals.append((ts, time.perf_counter()))
                     continue
                 if part and size + len(ct) > self.max_batch_bytes:
                     flush()
                 part.append(ref)
                 cts[name] = ct
                 size += len(ct)
+            flush()
         except BaseException as e:
             stream_err = e
-        else:
-            flush()
-        # drain EVERY dispatched tile, even after an error, so no decode
-        # worker is left running and no tile's bad names are lost
+        # finish EVERY dispatched tile, even after an error, so no decode
+        # work is left running and no tile's bad names are lost
         tile_err = None
-        for f in futures:
+        try:
+            loop.drain()
+        except BaseException as e:          # unexpected: not an
+            tile_err = e                    # IntegrityError (the loop
+        for f in futures:                   # and _decode_tile catch those)
             try:
-                results.append(f.result())
-            except BaseException as e:      # unexpected: not an
-                if tile_err is None:        # IntegrityError (_decode_tile
-                    tile_err = e            # catches those)
-        busy = busy_inline
-        for plains, bad, tile_wall in results:
+                plains, bad, interval = f.result()
+            except BaseException as e:
+                if tile_err is None:
+                    tile_err = e
+                continue
+            results.append((plains, bad))
+            intervals.append(interval)
+        results += loop.results
+        intervals += loop.intervals
+        for plains, bad in results:
             out.update(plains)
             bad_names.extend(bad)
-            busy += tile_wall
         if stream_err is not None:          # fetch failure dominates
             raise stream_err
         if tile_err is not None:
@@ -627,8 +651,11 @@ class BatchDecoder:
                 f"chunk ciphertext hash mismatch: {sorted(bad_names)}",
                 sorted(bad_names))
         COUNTERS.add("decode.batched_chunks", len(out))
-        return out, {"busy_s": busy, "wall_s": time.perf_counter() - t0,
-                     "tiles": len(results), "eager_flushes": eager_flushes,
+        return out, {"busy_s": _covered_s(intervals),
+                     "wall_s": time.perf_counter() - t0,
+                     "tiles": len(results),
+                     "tiles_overlapped": loop.overlapped,
+                     "eager_flushes": eager_flushes,
                      "eager_holds": eager_holds}
 
     # --------------------------------------------------- forward direction
@@ -702,12 +729,12 @@ class BatchDecoder:
         self._pool.shutdown()
 
     def _decode_tile_timed(self, part: list, ciphertexts: dict) -> tuple:
-        """``_decode_tile`` plus its own wall time (runs on a pool
-        thread; the per-tile walls sum to the stream's decode busy
+        """``_decode_tile`` plus its (start, end) (runs on a pool thread;
+        the union of the tiles' intervals is the stream's decode busy
         time)."""
         t0 = time.perf_counter()
         plains, bad = self._decode_tile(part, ciphertexts)
-        return plains, bad, time.perf_counter() - t0
+        return plains, bad, (t0, time.perf_counter())
 
     def _decode_tile(self, part: list, ciphertexts: dict) -> tuple:
         """One tile through the batched verify+decrypt pass. Returns
@@ -739,3 +766,96 @@ class BatchDecoder:
             size += n
         if part:
             yield part
+
+
+def _covered_s(intervals: list) -> float:
+    """Seconds covered by at least one of the (start, end) intervals."""
+    total, reach = 0.0, float("-inf")
+    for start, end in sorted(intervals):
+        if end > reach:
+            total += end - max(start, reach)
+            reach = end
+    return total
+
+
+class _TileLoop:
+    """One decode call's tile loop on the calling thread; tiles finish in
+    the order they are pushed.
+
+    Where the backend's ``fused`` hook is two-phase — it offers
+    ``submit(ciphertexts, keys) -> handle``, and ``handle.result()``
+    gives (digests, plaintexts) — up to ``PIPELINE_DEPTH`` tiles are in
+    flight: a pushed tile is packed and dispatched (``repro.decode.submit``)
+    before the loop waits on the tile before it (``repro.decode.tile``:
+    readback, split, the digest check), so the host's work on one tile
+    runs while the device works on the other. Otherwise each tile runs
+    start to end through ``_decode_tile``.
+
+    ``results`` holds ({name: plaintext}, [bad names]) per finished tile
+    (no bad chunk's plaintext among them), ``intervals`` each tile's
+    (start, end) from its submit to its finish, ``overlapped`` the
+    tiles submitted while another was in flight
+    (``decode.tiles_overlapped``)."""
+
+    def __init__(self, decoder: BatchDecoder):
+        self._decoder = decoder
+        self._submit = getattr(decoder._fused, "submit", None)
+        self._inflight: deque = deque()
+        self.results: list = []
+        self.intervals: list = []
+        self.overlapped = 0
+
+    def push(self, part: list, ciphertexts: dict) -> None:
+        t0 = time.perf_counter()
+        if self._submit is None:
+            self.results.append(self._decoder._decode_tile(part, ciphertexts))
+            self.intervals.append((t0, time.perf_counter()))
+            return
+        cts = [ciphertexts[r.name] for r in part]
+        nbytes = sum(map(len, cts))
+        with span("repro.decode.submit", chunks=len(cts), bytes=nbytes):
+            handle = self._submit(cts, [r.key for r in part])
+        if self._inflight:
+            self.overlapped += 1
+            COUNTERS.inc("decode.tiles_overlapped")
+        self._inflight.append((part, nbytes, handle, t0))
+        if len(self._inflight) >= PIPELINE_DEPTH:
+            self._finish()
+
+    def run(self, tiles, ciphertexts: dict) -> list:
+        """Push every tile and finish them all; returns ``results``."""
+        try:
+            for part in tiles:
+                self.push(part, ciphertexts)
+        finally:
+            self.drain()
+        return self.results
+
+    def drain(self) -> None:
+        """Finish every tile in flight; then raise the first error one of
+        them raised (a tampered chunk is a result, not an error)."""
+        err = None
+        while self._inflight:
+            try:
+                self._finish()
+            except BaseException as e:
+                if err is None:
+                    err = e
+        if err is not None:
+            raise err
+
+    def _finish(self) -> None:
+        inflight = len(self._inflight)
+        part, nbytes, handle, t0 = self._inflight.popleft()
+        with span("repro.decode.tile", chunks=len(part), bytes=nbytes,
+                  inflight=inflight):
+            digests, plains = handle.result()
+            try:
+                convergent.check_digests(digests, [r.sha256 for r in part])
+            except convergent.IntegrityError as e:
+                self.results.append(
+                    ({}, [part[i].name for i in e.bad_positions]))
+            else:
+                self.results.append(
+                    ({r.name: p for r, p in zip(part, plains)}, []))
+        self.intervals.append((t0, time.perf_counter()))

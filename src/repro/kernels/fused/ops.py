@@ -8,11 +8,20 @@ plaintexts byte-identical to the serial CTR oracle. The caller
 chunk names BEFORE releasing any plaintext, so per-chunk tamper
 detection and the eviction/retry semantics are unchanged.
 
+The pass has two phases: ``submit`` packs and dispatches a tile and
+returns a ``FusedTile`` at once, ``FusedTile.result()`` waits for the
+kernel and splits its output into per-chunk bytes. A caller that submits
+the next tile before it asks for the previous one's result keeps the
+device busy while the host marshals (``core.decode``'s tile loop does);
+``fused_verify_decrypt`` is ``submit(...).result()``, and the hook
+offers ``submit`` as its attribute.
+
 Marshalling is the SHA kernel's (``sha256.ops.pack_messages``): one
 padded byte row per chunk, word swap and transpose on the device. The
 round keys travel as per-chunk 0/-1 bit planes (``round_key_planes``).
-Each call is four ``repro.kernel.*`` spans (pack, dispatch, readback,
-split) and adds its copies to the ``xfer.*`` counters.
+Each tile is four ``repro.kernel.*`` spans (pack and dispatch in
+``submit``, readback and split in ``result``) and adds its copies to the
+``xfer.*`` counters.
 """
 from __future__ import annotations
 
@@ -59,18 +68,36 @@ def _fused_device(buf, nb, rk_planes, *, rounds: int, pallas: bool,
     return dig, bytes_from_words(plain)
 
 
-def fused_verify_decrypt(cts: list, keys: list, *,
-                         interpret: bool | None = None,
-                         pallas: bool | None = None) -> tuple:
-    """One fused device pass over N ciphertext chunks: returns
-    (digests, plaintexts) — digests[i] == sha256(cts[i]).digest() and
-    plaintexts[i] == AES-CTR(keys[i], zero IV) ^ cts[i], both as bytes.
-    ``pallas=None`` routes through the Pallas kernel on TPU and the
-    whole-tile XLA jit elsewhere; ``interpret`` only applies to the
-    Pallas route."""
-    n = len(cts)
-    if n == 0:
-        return [], []
+class FusedTile:
+    """One submitted fused pass. The copies of its digests and plaintext
+    back to the host start as soon as the kernel ends; ``result()``
+    waits for them and returns (digests, plaintexts) as bytes."""
+
+    __slots__ = ("_lens", "_dig", "_plain")
+
+    def __init__(self, lens: list, dig=None, plain=None):
+        self._lens, self._dig, self._plain = lens, dig, plain
+
+    def result(self) -> tuple:
+        if not self._lens:
+            return [], []
+        d2h = self._dig.nbytes + self._plain.nbytes
+        with span("repro.kernel.readback", d2h_bytes=d2h):
+            dig = np.asarray(self._dig)
+            plain = np.asarray(self._plain).view(np.uint8)
+        COUNTERS.add(D2H_BYTES, d2h)
+        with span("repro.kernel.split"):
+            return (digests_to_bytes(dig, len(self._lens)),
+                    [plain[i, :n].tobytes() for i, n in enumerate(self._lens)])
+
+
+def submit(cts: list, keys: list, *, interpret: bool | None = None,
+           pallas: bool | None = None) -> FusedTile:
+    """Pack N ciphertext chunks and their AES keys and launch one fused
+    device pass over them; returns without waiting for it (see
+    ``fused_verify_decrypt`` for what ``result()`` then gives)."""
+    if not cts:
+        return FusedTile([])
     if pallas is None:
         pallas = on_tpu()
     if pallas:
@@ -85,12 +112,22 @@ def fused_verify_decrypt(cts: list, keys: list, *,
     with span("repro.kernel.dispatch", h2d_bytes=h2d):
         dig, plain = _fused_device(buf, nb, rk, rounds=rk.shape[0] - 1,
                                    pallas=pallas, interpret=interpret)
+        dig.copy_to_host_async()
+        plain.copy_to_host_async()
     COUNTERS.add(H2D_BYTES, h2d)
-    d2h = dig.nbytes + plain.nbytes
-    with span("repro.kernel.readback", d2h_bytes=d2h):
-        dig = np.asarray(dig)
-        plain = np.asarray(plain).view(np.uint8)
-    COUNTERS.add(D2H_BYTES, d2h)
-    with span("repro.kernel.split"):
-        return (digests_to_bytes(dig, n),
-                [plain[i, :len(ct)].tobytes() for i, ct in enumerate(cts)])
+    return FusedTile([len(ct) for ct in cts], dig, plain)
+
+
+def fused_verify_decrypt(cts: list, keys: list, *,
+                         interpret: bool | None = None,
+                         pallas: bool | None = None) -> tuple:
+    """One fused device pass over N ciphertext chunks: returns
+    (digests, plaintexts) — digests[i] == sha256(cts[i]).digest() and
+    plaintexts[i] == AES-CTR(keys[i], zero IV) ^ cts[i], both as bytes.
+    ``pallas=None`` routes through the Pallas kernel on TPU and the
+    whole-tile XLA jit elsewhere; ``interpret`` only applies to the
+    Pallas route."""
+    return submit(cts, keys, interpret=interpret, pallas=pallas).result()
+
+
+fused_verify_decrypt.submit = submit

@@ -153,6 +153,8 @@ def _cold_start_admitted(model, manifest_blob, tenant_key, service, root,
             "overlap_fraction": lb.get("overlap_fraction"),
             "queue_hwm": lb.get("queue_hwm"),
             "eager_flushes": lb.get("eager_flushes"),
+            "decode_tiles": lb.get("decode_tiles"),
+            "tiles_overlapped": lb.get("tiles_overlapped"),
         }
         return engine, stats
 

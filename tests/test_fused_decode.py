@@ -47,6 +47,27 @@ def test_fused_boundary_lengths_match_oracles(route):
     assert fused_verify_decrypt([], []) == ([], [])
 
 
+@pytest.mark.parametrize("route", ["jit", "pallas"])
+def test_fused_submit_then_result_equals_one_call(route):
+    """Two tiles submitted before either result is read: each
+    ``submit(...).result()`` equals the one-call
+    ``fused_verify_decrypt``, which equals hashlib and the serial CTR
+    oracle."""
+    from repro.kernels.fused.ops import submit
+
+    assert fused_verify_decrypt.submit is submit
+    kw = ({"pallas": False} if route == "jit"
+          else {"pallas": True, "interpret": True})
+    tiles = [_batch([4096, 55, 1000]), _batch([64, 0, 4096])]
+    handles = [submit(cts, keys, **kw) for cts, keys in tiles]
+    for (cts, keys), handle in zip(tiles, handles):
+        got = handle.result()
+        assert got == fused_verify_decrypt(cts, keys, **kw)
+        assert got == ([hashlib.sha256(ct).digest() for ct in cts],
+                       [aes.ctr_decrypt(ct, k) for ct, k in zip(cts, keys)])
+    assert submit([], [], **kw).result() == ([], [])
+
+
 def test_fused_kernel_multi_lane_tile_multi_step():
     """The Pallas kernel over a (2 lane tiles) x (2 block steps) grid:
     each lane tile restarts its digest state, carries it across steps,

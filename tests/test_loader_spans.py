@@ -239,6 +239,101 @@ def test_fused_adapter_counts_what_it_copies(tmp_path, monkeypatch):
     assert after[D2H_BYTES] - before.get(D2H_BYTES, 0) == d2h
 
 
+def test_fused_submits_count_each_tile_as_before(tmp_path, monkeypatch):
+    """Two tiles submitted before either result is read: each tile's
+    dispatch and readback spans carry the bytes of its own copies, and
+    the ``xfer.*`` counters move by the sum, as two one-call passes
+    would move them."""
+    rng = np.random.default_rng(6)
+    tiles = []
+    for lens in ((CS, CS, 1000), (CS, 17)):
+        cts = [rng.integers(0, 256, n, np.uint8).tobytes() for n in lens]
+        tiles.append((cts, [bytes(rng.integers(0, 256, 32, np.uint8))
+                            for _ in cts]))
+    h2d, d2h = [], []
+    for cts, keys in tiles:
+        buf, nb = pack_messages(cts)
+        rk = fused_ops.round_key_planes(keys, buf.shape[0])
+        h2d.append(buf.nbytes + nb.nbytes + rk.nbytes)
+        # int32 digest words (8 a lane) and plaintext rows like `buf`
+        d2h.append(8 * buf.shape[0] * 4 + buf.nbytes)
+
+    def device(buf, nb, rk, **kw):
+        return (jax.numpy.zeros((8, buf.shape[0]), np.int32),
+                jax.numpy.zeros(buf.shape, np.int32))
+    monkeypatch.setattr(fused_ops, "_fused_device", device)
+    before = COUNTERS.snapshot()
+    with capture(tmp_path) as events:
+        handles = [fused_ops.submit(cts, keys, pallas=False)
+                   for cts, keys in tiles]
+        for handle in handles:
+            handle.result()
+    after = COUNTERS.snapshot()
+    dispatch = sorted(named(events, "repro.kernel.dispatch"),
+                      key=lambda e: e["start"])
+    readback = sorted(named(events, "repro.kernel.readback"),
+                      key=lambda e: e["start"])
+    assert [e["stats"]["h2d_bytes"] for e in dispatch] == h2d
+    assert [e["stats"]["d2h_bytes"] for e in readback] == d2h
+    assert dispatch[1]["end"] <= readback[0]["start"]
+    assert after[H2D_BYTES] - before.get(H2D_BYTES, 0) == sum(h2d)
+    assert after[D2H_BYTES] - before.get(D2H_BYTES, 0) == sum(d2h)
+
+
+def test_fused_coldstart_spans_nest_per_thread(tmp_path):
+    """A cold start through the fused backend (XLA route on the CPU),
+    two chunks per tile, four tiles: each tile is a ``repro.decode.submit``
+    span (pack, dispatch) and a later ``repro.decode.tile`` span
+    (readback, split, digest check); no two ``repro.decode.*`` spans of
+    one thread overlap, and every ``repro.kernel.*`` span lies inside a
+    ``repro.decode.*`` span of its thread. Tiles 2-4 were submitted
+    while the one before was in flight."""
+    store, root, blob, tree, template = image(tmp_path)
+    service = ImageService(store, ServiceConfig(
+        root=root, l2_nodes=0, decode_backend="bitsliced-fused",
+        max_batch_bytes=2 * CS))
+    before = COUNTERS.snapshot()
+    try:
+        with capture(tmp_path) as events:
+            engine, stats = cold_start(_TinyModel(template), blob, KEY,
+                                       service)
+    finally:
+        service.close()
+    after = COUNTERS.snapshot()
+    for k, v in tree.items():
+        assert np.array_equal(np.asarray(engine.params[k]), v)
+    assert stats["decode_tiles"] == 4 and stats["tiles_overlapped"] == 3
+    assert after["decode.tiles_overlapped"] - before.get(
+        "decode.tiles_overlapped", 0) == 3
+
+    (restore,) = named(events, "repro.restore")
+    submits = named(events, "repro.decode.submit")
+    tiles = named(events, "repro.decode.tile")
+    assert len(submits) == len(tiles) == 4
+    assert all(e["stats"]["chunks"] == 2 and e["stats"]["bytes"] == 2 * CS
+               for e in submits + tiles)
+    assert sorted(t["stats"]["inflight"] for t in tiles) == [1, 2, 2, 2]
+    decode = submits + tiles
+    assert all(inside(e, restore) for e in decode)
+    for a in decode:
+        for b in decode:
+            if a is not b and a["line"] == b["line"]:
+                assert a["end"] <= b["start"] or b["end"] <= a["start"]
+    kernel = [e for e in events if e["name"].startswith("repro.kernel.")]
+    assert len(kernel) == 16
+    for k in kernel:
+        assert any(inside(k, d) and k["line"] == d["line"] for d in decode)
+    for name, outer in (("repro.kernel.pack", submits),
+                        ("repro.kernel.dispatch", submits),
+                        ("repro.kernel.readback", tiles),
+                        ("repro.kernel.split", tiles)):
+        assert all(any(inside(k, d) for d in outer)
+                   for k in named(events, name)), name
+    for counter, stat in ((H2D_BYTES, "h2d_bytes"), (D2H_BYTES, "d2h_bytes")):
+        assert after.get(counter, 0) - before.get(counter, 0) == sum(
+            e["stats"].get(stat, 0) for e in events) > 0
+
+
 class _SlowStore(ChunkStore):
     """Chunk GETs take `delay_s` each."""
 

@@ -5,6 +5,7 @@ invariants, hypothesis property tests for tiling and stream/staged
 equivalence, tamper-mid-stream ordered error aggregation (with L1
 eviction of bad ciphertexts), the ``decrypt_batch`` shared-state footgun
 warning, and thread-exactness of the telemetry primitives."""
+import hashlib
 import random
 import threading
 import time
@@ -17,7 +18,7 @@ from hypothesis import strategies as st
 
 from repro.core.cache.local import LocalCache
 from repro.core.concurrency import BoundedQueue
-from repro.core.crypto import convergent
+from repro.core.crypto import aes, convergent
 from repro.core.decode import BatchDecoder
 from repro.core.loader import ImageReader
 from repro.core.manifest import ZERO_CHUNK
@@ -250,6 +251,147 @@ def test_stream_tiles_equal_staged_batch_any_order(nchunks, max_bytes, seed):
     plains, stats = dec.decrypt_stream(q, {r.name: r for r in refs})
     assert plains == staged == want
     assert stats["busy_s"] >= 0.0
+
+
+# ------------------------------------------- two-phase (pipelined) tiles
+
+class TwoPhaseHook:
+    """A two-phase ``fused`` hook (hashlib + the serial CTR oracle) that
+    logs each ``submit`` and ``result`` and how many tiles were in
+    flight; ``result()`` sleeps ``result_sleep_s`` first."""
+
+    def __init__(self, result_sleep_s=0.0):
+        self.log: list = []
+        self.inflight = 0
+        self.max_inflight = 0
+        self.result_sleep_s = result_sleep_s
+
+    def __call__(self, cts, keys):
+        return self.submit(cts, keys).result()
+
+    def submit(self, cts, keys):
+        tile = sum(1 for op, _ in self.log if op == "submit")
+        self.log.append(("submit", tile))
+        self.inflight += 1
+        self.max_inflight = max(self.max_inflight, self.inflight)
+        hook = self
+
+        class Handle:
+            def result(self):
+                time.sleep(hook.result_sleep_s)
+                hook.log.append(("result", tile))
+                hook.inflight -= 1
+                return ([hashlib.sha256(c).digest() for c in cts],
+                        [aes.ctr_decrypt(c, k) for c, k in zip(cts, keys)])
+        return Handle()
+
+
+def _decoder(hook, max_batch_bytes):
+    """A single-threaded decoder whose ``fused`` hook is `hook`
+    (``None``: the real ``bitsliced-fused`` adapter, XLA route)."""
+    if hook is None:
+        return BatchDecoder("bitsliced-fused", max_batch_bytes=max_batch_bytes)
+    dec = BatchDecoder("python", max_batch_bytes=max_batch_bytes, threads=1)
+    dec._fused = hook
+    return dec
+
+
+def _stream(refs, cts, order=None):
+    q = BoundedQueue(len(refs) + 1)
+    for r in (refs if order is None else [refs[i] for i in order]):
+        q.put((r.name, cts[r.name]))
+    q.close()
+    return q
+
+
+# 11 chunks in 2-chunk tiles: 6 tiles, the last one short
+PIPE_LENS = [4096, 4096, 100, 4096, 4096, 4096, 55, 4096, 4096, 4096, 7]
+
+
+@pytest.mark.parametrize("hook", ["stub", "adapter"])
+def test_pipelined_tiles_match_serial_oracle(hook):
+    """Streamed and staged decodes through a two-phase hook equal the
+    serial oracle over 6 tiles, the last one short; at most 2 tiles
+    are ever in flight, every tile but the first is submitted while the
+    one before it is, and tile i+1 is submitted before tile i's result
+    is read."""
+    refs, cts, want = _synthetic_batch(PIPE_LENS)
+    serial = BatchDecoder("serial").decrypt_batch(refs, cts)
+    stub = TwoPhaseHook() if hook == "stub" else None
+    dec = _decoder(stub, 2 * 4096)
+    before = COUNTERS.get("decode.tiles_overlapped")
+    plains, stats = dec.decrypt_stream(_stream(refs, cts),
+                                       {r.name: r for r in refs})
+    assert plains == serial == want
+    assert stats["tiles"] == 6
+    assert stats["tiles_overlapped"] == stats["tiles"] - 1
+    assert COUNTERS.get("decode.tiles_overlapped") - before == 5
+    assert dec.decrypt_batch(refs, cts) == want
+    if stub is not None:
+        assert stub.max_inflight == 2 and stub.inflight == 0
+        # stream, then the staged batch: each submits tile i+1 first
+        for start in (0, 6):
+            ops = [op for op in stub.log if start <= op[1] < start + 6]
+            assert ops[:3] == [("submit", start), ("submit", start + 1),
+                               ("result", start)]
+            assert ops[-1] == ("result", start + 5)
+
+
+@pytest.mark.parametrize("hook", ["stub", "adapter"])
+def test_pipelined_tamper_names_only_the_bad_chunk(hook):
+    """A chunk tampered in a middle tile is the only name in the
+    ``IntegrityError``, and its tile releases no plaintext, while the
+    tiles around it still decode."""
+    from repro.core.decode import _TileLoop
+
+    refs, cts, want = _synthetic_batch(PIPE_LENS)
+    victim = refs[5].name                   # tile 2 of 6
+    bad = dict(cts)
+    mid = len(bad[victim]) // 2
+    bad[victim] = (bad[victim][:mid] + bytes([bad[victim][mid] ^ 0x10])
+                   + bad[victim][mid + 1:])
+    dec = _decoder(TwoPhaseHook() if hook == "stub" else None, 2 * 4096)
+    with pytest.raises(convergent.IntegrityError) as ei:
+        dec.decrypt_stream(_stream(refs, bad), {r.name: r for r in refs})
+    assert ei.value.bad_positions == [victim]
+    tiles = list(dec._split(refs, bad))
+    results = _TileLoop(dec).run(tiles, bad)
+    assert [b for _, b in results] == [[], [], [victim], [], [], []]
+    released = {n: p for plains, _ in results for n, p in plains.items()}
+    assert victim not in released and refs[4].name not in released
+    assert released == {n: p for n, p in want.items()
+                        if n not in (victim, refs[4].name)}
+
+
+def test_pipelined_poisoned_queue_raises_after_inflight_tile():
+    """A fetch failure that reaches the consumer while a tile is in
+    flight re-raises only once that tile's result has been read."""
+    refs, cts, _ = _synthetic_batch([4096] * 5)
+    stub = TwoPhaseHook(result_sleep_s=0.05)
+    dec = _decoder(stub, 2 * 4096)
+    q = BoundedQueue(8)
+    for r in refs:
+        q.put((r.name, cts[r.name]))
+    q.poison(OSError("origin went away"))
+    with pytest.raises(OSError, match="origin went away"):
+        dec.decrypt_stream(q, {r.name: r for r in refs})
+    # tiles 0 and 1 were submitted; tile 1 was in flight at the poison
+    assert stub.log == [("submit", 0), ("submit", 1), ("result", 0),
+                        ("result", 1)]
+    assert stub.inflight == 0
+
+
+def test_pipelined_busy_is_the_union_of_tiles_in_flight():
+    """Overlapping tiles count once: with each result taking 30 ms,
+    ``busy_s`` covers every wait but never passes the stream's wall
+    (summing each tile's submit-to-result time would: the next tile is
+    in flight through each wait)."""
+    refs, cts, _ = _synthetic_batch([4096] * 12)
+    stub = TwoPhaseHook(result_sleep_s=0.03)
+    plains, stats = _decoder(stub, 2 * 4096).decrypt_stream(
+        _stream(refs, cts), {r.name: r for r in refs})
+    assert len(plains) == 12 and stats["tiles"] == 6
+    assert 6 * 0.03 <= stats["busy_s"] <= stats["wall_s"]
 
 
 # --------------------------------------------------- tamper mid-stream
