@@ -32,16 +32,29 @@ class ModelConfig:
     first_dense_layers: int = 0    # kimi: leading dense layers
     dense_d_ff: int = 0            # d_ff used by dense layers in a MoE model
     capacity_factor: float = 1.25
+    # expert parallelism: this replica holds the experts of rank
+    # `ep_rank` of `ep_size` (``held_experts``); the router keeps all
+    ep_size: int = 1
+    ep_rank: int = 0
 
     # --- hybrid (jamba) ---
     attn_period: int = 0        # attention every `attn_period` layers (0 = all attn)
     attn_offset: int = 0        # which slot in the period is attention
 
     # --- ssm ---
-    ssm_type: str = ""          # "mamba" | "xlstm"
+    ssm_type: str = ""          # "mamba" | "mamba2" | "xlstm"
     d_state: int = 16
     d_conv: int = 4
     ssm_expand: int = 2
+    ssm_head_dim: int = 64      # mamba2: d_inner // ssm_head_dim heads
+    ssm_groups: int = 1         # mamba2: groups sharing B and C
+    ssm_chunk: int = 256        # mamba2: chunk length of the SSD scan
+
+    # --- granite multipliers (the defaults leave the graph as it was) ---
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0     # logits are divided by it
+    attention_multiplier: float = 0.0   # 0 -> 1/sqrt(head_dim)
 
     # --- mlp / norm / positional ---
     mlp_type: str = "swiglu"    # swiglu | gelu
@@ -63,6 +76,7 @@ class ModelConfig:
     use_bias: bool = False      # linear-layer biases (starcoder2/whisper style)
     # attention flavors
     sliding_window: int = 0     # 0 = full attention
+    param_dtype: str = "float32"    # dtype of every parameter leaf
 
     def __post_init__(self):
         if self.head_dim == 0:
@@ -77,6 +91,12 @@ class ModelConfig:
     @property
     def kv_dim(self) -> int:
         return self.num_kv_heads * self.head_dim
+
+    @property
+    def held_experts(self) -> tuple:
+        """[lo, hi) of the experts this replica holds."""
+        E, n, r = self.num_experts, self.ep_size, self.ep_rank
+        return E * r // n, E * (r + 1) // n
 
     @property
     def is_subquadratic(self) -> bool:

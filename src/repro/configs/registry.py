@@ -16,6 +16,7 @@ _ARCH_MODULES = {
     "xlstm-350m": "repro.configs.xlstm_350m",
     "pixtral-12b": "repro.configs.pixtral_12b",
     "jamba-v0.1-52b": "repro.configs.jamba_52b",
+    "granite-4.0-h-small": "repro.configs.granite_4_0_h_small",
 }
 
 

@@ -813,6 +813,7 @@ class TieredReader:
         self.counters.add("read.batched_chunks", nchunks)
         self.last_batch = {
             "chunks": nchunks,
+            "names": len(fb.by_name),
             "fetched": len(fb.by_name) - fb.l1_hits,
             "parallelism": int(parallelism),
             "sim_serial_s": fb.l1_lat + sum(fetch_lats),
@@ -868,6 +869,7 @@ class TieredReader:
         self.counters.max_update("stream.queue_hwm", q.high_water)
         self.last_batch = {
             "chunks": nchunks,
+            "names": len(fb.by_name),
             "fetched": len(fb.by_name) - fb.l1_hits,
             "parallelism": int(parallelism),
             "wall_s": time.perf_counter() - t0,
@@ -983,6 +985,7 @@ class TieredReader:
         self.counters.max_update("stream.queue_hwm", q.high_water)
         self.last_batch = {
             "chunks": nchunks,
+            "names": len(fb.by_name),
             "fetched": len(fb.by_name) - fb.l1_hits,
             "parallelism": int(parallelism),
             "sim_serial_s": fb.l1_lat + sum(fetch_lats),

@@ -19,8 +19,8 @@ module is that agent:
   per-tenant scoped counters (``service.tenant_counters(t)``).
 * ``ImageHandle`` — a session over one opened image
   (``service.open(manifest_blob, tenant_key, root=...)``). Its read
-  methods (``restore_tree`` / ``restore_shards`` / ``tensor_shard`` /
-  ``prefetch`` / ``tensor``) take a single optional ``ReadPolicy``
+  methods (``restore_tree`` / ``restore_share`` / ``restore_shards`` /
+  ``tensor_shard`` / ``prefetch`` / ``tensor``) take a single optional ``ReadPolicy``
   instead of the scattered ``batched=/streamed=/parallelism=`` keyword
   tuple the pre-redesign API threaded through every layer.
 * ``ReadPolicy`` — how one read should run: pipeline ``mode``
@@ -70,7 +70,7 @@ from repro.core.layout import (
     read_tensor,
     shard_byte_ranges,
 )
-from repro.core.manifest import open_manifest
+from repro.core.manifest import ZERO_CHUNK, open_manifest
 from repro.core.publish import PublishPipeline
 from repro.core.retry import CircuitBreaker, RetryPolicy
 from repro.core.telemetry import COUNTERS, ScopedCounters, span
@@ -669,6 +669,57 @@ class ImageHandle:
                 # to the serial read_tensor path
                 out[name] = np.frombuffer(raw, dt).reshape(shape)
         return out
+
+    def restore_share(self, share: dict,
+                      policy: ReadPolicy | None = None) -> tuple:
+        """A replica's share of the image, {path: dim slices | None (the
+        whole tensor)}, restored in one ``restore_shards`` batch under
+        `policy`. Returns (flat {path: array}, the share's numbers):
+        ``held_bytes``, ``image_bytes`` (all of the image's tensors),
+        ``chunks`` (the chunk ciphertexts the restore fetched through the
+        tiers, counted by the read itself: chunks of equal bytes are one
+        name and zero chunks are never fetched), ``chunks_covered`` (the
+        chunks the share's byte ranges lie in, from the layout) and
+        ``partial_chunks`` (covered chunks of which the share holds only
+        part of the tensor bytes). One ``repro.restore.share`` span
+        carries them; the counters ``restore.share_bytes`` and
+        ``restore.skipped_bytes`` add up the bytes held and left in the
+        image."""
+        cs = self.manifest.chunk_size
+        serial = self._resolve(policy)[0].mode == "serial"
+        if serial:
+            names = {c.index: c.name for c in self.manifest.chunks}
+        held = covered_n = partial = serial_reads = 0
+        for name, sl in share.items():
+            t = self.layout.tensors[name]
+            ranges = ([(t.offset, t.nbytes)] if sl is None or not t.shape
+                      else shard_byte_ranges(t, sl))
+            covered: dict = {}          # chunk -> bytes of it read
+            for off, ln in ranges:
+                held += ln
+                if serial:      # the oracle fetches each range's chunks
+                    serial_reads += sum(names[c] != ZERO_CHUNK for c in
+                                        ranges_to_chunks([(off, ln)], cs))
+                while ln > 0:
+                    n = min(ln, cs - off % cs)
+                    covered[off // cs] = covered.get(off // cs, 0) + n
+                    off, ln = off + n, ln - n
+            end = t.offset + t.nbytes   # tensors start on a chunk
+            covered_n += len(covered)
+            partial += sum(n < min(end, (c + 1) * cs) - c * cs
+                           for c, n in covered.items())
+        image = sum(t.nbytes for t in self.layout.tensors.values())
+        numbers = {"held_bytes": held, "image_bytes": image,
+                   "chunks_covered": covered_n, "partial_chunks": partial}
+        with span("repro.restore.share", **numbers) as s:
+            flat = self.restore_shards(share, policy)
+            # what the read fetched: the batch's distinct ciphertexts
+            numbers["chunks"] = (serial_reads if serial
+                                 else self.reader.last_batch["names"])
+            s.set_metadata(chunks=numbers["chunks"])
+        self.counters.inc("restore.share_bytes", held)
+        self.counters.inc("restore.skipped_bytes", image - held)
+        return flat, numbers
 
     def tensor_shard(self, name: str, dim_slices: list,
                      policy: ReadPolicy | None = None) -> np.ndarray:

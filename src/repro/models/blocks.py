@@ -1,6 +1,8 @@
 """Sublayer = (norm -> mixer -> residual) [+ (norm -> ffn -> residual)].
 
-Mixers: attn | cross_attn | mamba | mlstm | slstm. FFNs: dense | moe | none.
+Mixers: attn | cross_attn | mamba | mamba2 | mlstm | slstm. FFNs: dense |
+moe | none. Each sublayer's output joins the residual stream scaled by
+``cfg.residual_multiplier`` (granite; 1.0 elsewhere).
 One ``sublayer_apply`` covers train/encode/prefill/decode so every
 architecture family assembles from the same parts (see ``lm.layout``).
 """
@@ -14,6 +16,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn_mod
 from repro.models import mamba as mamba_mod
+from repro.models import mamba2 as mamba2_mod
 from repro.models import xlstm as xlstm_mod
 from repro.models.common import apply_norm, apply_rope, dense_init, norm_init
 from repro.models.mlp import mlp_apply, mlp_init
@@ -22,7 +25,7 @@ from repro.sharding.constrain import logical_constraint
 
 
 class SubDef(NamedTuple):
-    mixer: str          # attn | cross_attn | mamba | mlstm | slstm
+    mixer: str          # attn | cross_attn | mamba | mamba2 | mlstm | slstm
     ffn: str            # dense | moe | none
     d_ff: int = 0       # 0 -> cfg.d_ff
     causal: bool = True
@@ -57,6 +60,9 @@ def _qkv(p, x, cfg: ModelConfig, dtype):
     q = _proj(p, x, "q", dtype, cfg).reshape(lead + (cfg.num_heads, cfg.head_dim))
     k = _proj(p, x, "k", dtype, cfg).reshape(lead + (cfg.num_kv_heads, cfg.head_dim))
     v = _proj(p, x, "v", dtype, cfg).reshape(lead + (cfg.num_kv_heads, cfg.head_dim))
+    if cfg.attention_multiplier:
+        # scores scaled by attention_multiplier, not 1/sqrt(head_dim)
+        q = q * (cfg.attention_multiplier * cfg.head_dim ** 0.5)
     return q, k, v
 
 
@@ -186,6 +192,8 @@ def sublayer_init(key, prefix: str, cfg: ModelConfig, sd: SubDef):
         p["mixer"], s["mixer"] = _attn_init(key, f"{prefix}.attn", cfg)
     elif sd.mixer == "mamba":
         p["mixer"], s["mixer"] = mamba_mod.mamba_init(key, f"{prefix}.mamba", cfg)
+    elif sd.mixer == "mamba2":
+        p["mixer"], s["mixer"] = mamba2_mod.mamba2_init(key, f"{prefix}.mamba2", cfg)
     elif sd.mixer == "mlstm":
         p["mixer"], s["mixer"] = xlstm_mod.mlstm_init(key, f"{prefix}.mlstm", cfg)
     elif sd.mixer == "slstm":
@@ -212,6 +220,8 @@ def sublayer_decode_state(cfg: ModelConfig, sd: SubDef, batch: int, max_len: int
         return {"k": kv, "v": kv}
     if sd.mixer == "mamba":
         return mamba_mod.mamba_decode_state(cfg, batch, dtype)
+    if sd.mixer == "mamba2":
+        return mamba2_mod.mamba2_decode_state(cfg, batch, dtype)
     if sd.mixer == "mlstm":
         return xlstm_mod.mlstm_decode_state(cfg, batch)
     if sd.mixer == "slstm":
@@ -226,6 +236,9 @@ def decode_state_specs(sd: SubDef):
                 "v": ("batch", "kv_seq", "kv_heads", None)}
     if sd.mixer == "mamba":
         return {"conv": ("batch", None, "tp"), "ssm": ("batch", "tp", None)}
+    if sd.mixer == "mamba2":
+        return {"conv": ("batch", None, "tp"),
+                "ssm": ("batch", "heads", None, None)}
     if sd.mixer == "mlstm":
         return {"C": ("batch", "heads", None, None),
                 "n": ("batch", "heads", None), "m": ("batch", "heads")}
@@ -239,13 +252,18 @@ def decode_state_specs(sd: SubDef):
 def _apply_ffn(p, x, cfg: ModelConfig, sd: SubDef, dtype, moe_impl="sort"):
     h = apply_norm(p["norm2"], x, cfg.norm_type, cfg.norm_eps)
     if sd.ffn == "moe":
-        if h.ndim == 2:
-            y = moe_apply(p["ffn"], h[:, None, :], cfg, dtype, impl=moe_impl)[:, 0]
+        if h.ndim == 2:             # decode: drops no token
+            y = moe_apply(p["ffn"], h[:, None, :], cfg, dtype, impl=moe_impl,
+                          dropless=True)[:, 0]
         else:
             y = moe_apply(p["ffn"], h, cfg, dtype, impl=moe_impl)
     else:
         y = mlp_apply(p["ffn"], h, cfg.mlp_type, dtype)
-    return x + y
+    return x + _residual(y, cfg)
+
+
+def _residual(y, cfg: ModelConfig):
+    return y if cfg.residual_multiplier == 1.0 else y * cfg.residual_multiplier
 
 
 def sublayer_apply(p, x, cfg: ModelConfig, sd: SubDef, dtype, *,
@@ -280,6 +298,15 @@ def sublayer_apply(p, x, cfg: ModelConfig, sd: SubDef, dtype, *,
                                                  return_state=True)
         else:
             y = mamba_mod.mamba_apply(p["mixer"], h, cfg, dtype)
+    elif sd.mixer == "mamba2":
+        if mode == "decode":
+            y, new_state = mamba2_mod.mamba2_decode(p["mixer"], h, state, cfg,
+                                                    dtype)
+        else:
+            y = mamba2_mod.mamba2_apply(p["mixer"], h, cfg, dtype,
+                                        return_state=mode == "prefill")
+            if mode == "prefill":
+                y, new_state = y
     elif sd.mixer == "mlstm":
         if mode == "decode":
             y, new_state = xlstm_mod.mlstm_decode(p["mixer"], h, state, cfg, dtype)
@@ -298,7 +325,7 @@ def sublayer_apply(p, x, cfg: ModelConfig, sd: SubDef, dtype, *,
             y = xlstm_mod.slstm_apply(p["mixer"], h, cfg, dtype)
     else:
         raise ValueError(sd.mixer)
-    x = x + y
+    x = x + _residual(y, cfg)
     if sd.ffn != "none":
         x = _apply_ffn(p, x, cfg, sd, dtype, moe_impl=moe_impl)
     return x, new_state

@@ -3,7 +3,10 @@
 Layers are grouped into homogeneous *superblocks* scanned with ``lax.scan``
 (stacked params) to keep HLO size and compile time flat in depth:
 dense/moe = 1-sublayer group, xlstm = (mlstm, slstm) pairs, jamba = the
-period-8 attn/mamba/MoE pattern. Remat policy applies per scanned body.
+period-8 attn/mamba/MoE pattern, granite the period-10 attn/mamba2/MoE one.
+Remat policy applies per scanned body. Parameters are made in
+``cfg.param_dtype``; a replica that holds a share of the experts states,
+per leaf, which slice of the published image it holds (``param_share``).
 """
 from __future__ import annotations
 
@@ -61,7 +64,7 @@ def layout(cfg: ModelConfig) -> list[tuple[int, list[SubDef]]]:
     if cfg.family == "hybrid":
         subs = []
         for i in range(cfg.attn_period):
-            mixer = "attn" if i == cfg.attn_offset else "mamba"
+            mixer = "attn" if i == cfg.attn_offset else cfg.ssm_type
             ffn = "moe" if (cfg.num_experts and i % cfg.moe_every == cfg.moe_offset) else "dense"
             subs.append(SubDef(mixer, ffn, cfg.dense_d_ff if ffn == "dense" else 0))
         return [(cfg.num_layers // cfg.attn_period, subs)]
@@ -88,6 +91,8 @@ class DecoderLM:
             specs[f"g{gi}"] = gs
         params["final_norm"], specs["final_norm"] = norm_init(cfg.d_model, cfg.norm_type)
         self._specs = specs
+        if cfg.param_dtype != "float32":
+            params = jax.tree.map(lambda a: a.astype(cfg.param_dtype), params)
         return params
 
     def _stack_group(self, key, gi: int, subs, repeats: int):
@@ -121,6 +126,25 @@ class DecoderLM:
 
     def param_shapes(self):
         return jax.eval_shape(self.init, jax.random.key(0))
+
+    def param_share(self) -> dict | None:
+        """This replica's share of the published image, leaf by leaf:
+        {path: per-dim (start, stop) in the image's tensor, or None where
+        the leaf is held whole}. The expert axis is the one the specs
+        name "expert"; None when every expert is held."""
+        lo, hi = self.cfg.held_experts
+        if (lo, hi) == (0, self.cfg.num_experts):
+            return None
+        shapes = jax.tree_util.tree_flatten_with_path(self.param_shapes())[0]
+        axes = jax.tree.leaves(self.param_specs(), is_leaf=lambda x: isinstance(
+            x, tuple) and all(isinstance(a, (str, type(None))) for a in x))
+        share = {}
+        for (path, leaf), ax in zip(shapes, axes):
+            name = "/".join(str(k.key) for k in path)
+            share[name] = None if "expert" not in ax else [
+                (lo, hi) if a == "expert" else (0, n)
+                for a, n in zip(ax, leaf.shape)]
+        return share
 
     # ------------------------------------------------------------- shared
     def _maybe_remat(self, body):
@@ -173,15 +197,22 @@ class DecoderLM:
     # -------------------------------------------------------------- embed
     def _embed(self, params, batch, dtype):
         cfg = self.cfg
-        x = embed_lookup(params["embed"], batch["tokens"], dtype)
+        x = self._scale_embed(
+            embed_lookup(params["embed"], batch["tokens"], dtype))
         if cfg.family == "vlm":
             x = jnp.concatenate([batch["patches"].astype(dtype), x], axis=1)
         x = logical_constraint(x, ("batch", "seq", None))
         return x
 
+    def _scale_embed(self, x):
+        m = self.cfg.embedding_multiplier
+        return x if m == 1.0 else x * m
+
     def _logits(self, params, x, dtype):
         table = params["embed"] if self.cfg.tie_embeddings else params["unembed"]
-        return x @ table.T.astype(dtype)
+        logits = x @ table.T.astype(dtype)
+        s = self.cfg.logits_scaling
+        return logits if s == 1.0 else logits / s
 
     # --------------------------------------------------------------- train
     def loss(self, params, batch):
@@ -197,6 +228,8 @@ class DecoderLM:
         labels = batch["labels"]
         table = params["embed"] if cfg.tie_embeddings else params["unembed"]
         if flags.chunked_loss:
+            if cfg.logits_scaling != 1.0:
+                x = x / cfg.logits_scaling
             return chunked_softmax_xent(x, table.astype(dtype), labels,
                                         cfg.vocab_size, flags.chunked_loss)
         logits = self._logits(params, x, dtype)
@@ -247,7 +280,8 @@ class DecoderLM:
         """tokens: (B,) int32; pos: (B,) positions being written."""
         cfg, flags = self.cfg, self.flags
         dtype = jnp.dtype(flags.dtype)
-        x = embed_lookup(params["embed"], tokens, dtype)        # (B, D)
+        x = self._scale_embed(
+            embed_lookup(params["embed"], tokens, dtype))       # (B, D)
         x, new_states = self._run_groups_state(
             params, x, dtype, "decode", state, pos=pos)
         x = apply_norm(params["final_norm"], x, cfg.norm_type, cfg.norm_eps)

@@ -9,6 +9,15 @@ spec so experts land on the `model` mesh axis (EP) and capacity on `data` —
 the token->expert exchange lowers to the all-to-all family under SPMD.
 
 Supports Arctic's dense-residual MoE and Kimi/DeepSeek shared experts.
+
+Expert parallelism without the exchange: a replica told that it holds
+experts ``cfg.held_experts`` = [lo, hi) keeps only their weights, routes
+every token over all ``num_experts`` with top-k, and adds the gated
+outputs of its own experts (plus the shared expert, which every replica
+holds whole). Assignments to other experts are not this replica's part
+of the result; they are never capacity drops. On the decode path
+(``dropless``) each held expert has a slot for every token, so no token
+is dropped.
 """
 from __future__ import annotations
 
@@ -22,9 +31,11 @@ from repro.sharding.constrain import logical_constraint
 
 
 def moe_init(key, prefix: str, cfg: ModelConfig):
-    E, D, F = cfg.num_experts, cfg.d_model, cfg.d_ff
+    lo, hi = cfg.held_experts
+    E, D, F = hi - lo, cfg.d_model, cfg.d_ff
     p, s = {}, {}
-    p["router"], s["router"] = dense_init(key, f"{prefix}.router", D, E, "fsdp", None)
+    p["router"], s["router"] = dense_init(key, f"{prefix}.router", D,
+                                          cfg.num_experts, "fsdp", None)
     fold = lambda nm: f"{prefix}.{nm}"
 
     def expert_stack(nm, a, b, in_ax, out_ax):
@@ -59,11 +70,20 @@ def _expert_ffn(p, x: jnp.ndarray, kind: str, dtype) -> jnp.ndarray:
     return jnp.einsum("ecf,efd->ecd", h, p["w_down"].astype(dtype))
 
 
+def route(logits: jnp.ndarray, k: int) -> tuple:
+    """Top-k over the router's logits, softmax over those k (the same
+    gates as top-k of the full softmax, renormalized). Returns
+    (gates, experts), each (T, k)."""
+    top, experts = jax.lax.top_k(logits, k)
+    return jax.nn.softmax(top, axis=-1), experts
+
+
 def moe_apply(p, x: jnp.ndarray, cfg: ModelConfig, dtype,
-              impl: str = "sort") -> jnp.ndarray:
+              impl: str = "sort", dropless: bool = False) -> jnp.ndarray:
     """x: (B, S, D) -> (B, S, D). impl: 'sort' (global sort+scatter under
-    SPMD) or 'shard_map' (explicit EP all-to-all; §Perf lever)."""
-    if impl == "shard_map" and x.ndim == 3:
+    SPMD) or 'shard_map' (explicit EP all-to-all; §Perf lever), which
+    needs every expert held. `dropless`: a slot for every token."""
+    if impl == "shard_map" and x.ndim == 3 and cfg.ep_size == 1:
         from repro.sharding.constrain import active_policy
         act = active_policy()
         if act is not None:
@@ -75,7 +95,7 @@ def moe_apply(p, x: jnp.ndarray, cfg: ModelConfig, dtype,
                 ep *= mesh.shape[a]
             if ep > 1 and cfg.num_experts % ep == 0:
                 return _moe_shard_map(p, x, cfg, dtype, mesh, policy, ep_axes)
-    return _moe_sort(p, x, cfg, dtype)
+    return _moe_sort(p, x, cfg, dtype, dropless)
 
 
 def _moe_shard_map(p, x, cfg: ModelConfig, dtype, mesh, policy, ep_axes):
@@ -121,9 +141,7 @@ def _moe_shard_map(p, x, cfg: ModelConfig, dtype, mesh, policy, ep_axes):
         T = Bl * Sl
         xf = xs.reshape(T, D)
         logits = (xf @ router.astype(dtype)).astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, experts = jax.lax.top_k(probs, K)
-        gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
+        gates, experts = route(logits, K)
 
         flat_e = experts.reshape(T * K)
         order = jnp.argsort(flat_e)
@@ -181,33 +199,37 @@ def _moe_shard_map(p, x, cfg: ModelConfig, dtype, mesh, policy, ep_axes):
     return y
 
 
-def _moe_sort(p, x: jnp.ndarray, cfg: ModelConfig, dtype) -> jnp.ndarray:
-    """x: (B, S, D) -> (B, S, D)."""
+def _moe_sort(p, x: jnp.ndarray, cfg: ModelConfig, dtype,
+              dropless: bool = False) -> jnp.ndarray:
+    """x: (B, S, D) -> (B, S, D): the held experts' part plus the shared."""
     B, S, D = x.shape
     E, K = cfg.num_experts, cfg.experts_per_token
+    lo, hi = cfg.held_experts
     T = B * S
     xf = x.reshape(T, D)
 
     logits = (xf @ p["router"].astype(dtype)).astype(jnp.float32)   # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, experts = jax.lax.top_k(probs, K)                        # (T, K)
-    gates = gates / jnp.maximum(jnp.sum(gates, -1, keepdims=True), 1e-9)
+    gates, experts = route(logits, K)                               # (T, K)
 
     # capacity per expert, rounded to 128 so the (E, C, D) buffer stays
-    # shardable on the data axis (TPU-aligned tile too)
-    cap = max(1, int(T * K * cfg.capacity_factor / E))
+    # shardable on the data axis (TPU-aligned tile too); a token takes at
+    # most one slot of an expert, so T slots drop nothing
+    cap = T if dropless else max(1, int(T * K * cfg.capacity_factor / E))
     cap = min(cap, T)
     if cap >= 128:
         cap = ((cap + 127) // 128) * 128
 
-    flat_e = experts.reshape(T * K)
+    # held experts by local index; others sort last, as index E
+    E = hi - lo
+    flat_e = experts.reshape(T * K) - lo
+    flat_e = jnp.where((flat_e >= 0) & (flat_e < E), flat_e, E)
     order = jnp.argsort(flat_e)                                     # stable
     sorted_e = flat_e[order]
     # slot of each sorted assignment within its expert
-    counts = jnp.bincount(sorted_e, length=E)
+    counts = jnp.bincount(sorted_e, length=E + 1)
     starts = jnp.cumsum(counts) - counts
     slot = jnp.arange(T * K) - starts[sorted_e]
-    keep = slot < cap
+    keep = (slot < cap) & (sorted_e < E)
     token_of = order // K
 
     # dispatch: (E*cap, D) buffer. Dropped assignments scatter to index

@@ -7,13 +7,14 @@ REJECTED with ``ColdStartRejected``, not queued), opens the image as a
 tenant session, restores the weights through the shared cache tiers
 under one ``ReadPolicy``, promotes any float64 leaves to float32 (the
 serving dtype; see the test asserting this), places the tree in device
-memory, and stands up a ``ServeEngine`` over it. For MoE configs,
-``expert_shard_restore`` restores only this worker's experts (EP
-sparsity: the demand-loading analogue of 'applications touch 6.4% of
-the image').
+memory, and stands up a ``ServeEngine`` over it. A replica that holds
+a share of the image (an expert-parallel rank: ``model.param_share()``)
+passes it as ``share`` and restores only its slices (EP sparsity: the
+demand-loading analogue of 'applications touch 6.4% of the image').
 
 Each cold start is one ``request_scope``: its ``repro.coldstart`` span
-holds ``.admit``, ``.open``, ``repro.restore`` and ``.place``, and
+holds ``.admit``, ``.open``, ``repro.restore`` (``repro.restore.share``
+for a share) and ``.place``, and
 every span of the start, on whichever thread, carries its ``request``.
 
 The pre-redesign calling convention — a raw store plus the
@@ -39,7 +40,7 @@ from repro.train.checkpoint import tree_from_flat
 
 def cold_start(model, manifest_blob: bytes, tenant_key: bytes, service, *,
                root=None, tenant=None, policy: ReadPolicy | None = None,
-               max_batch=4, max_len=128,
+               max_batch=4, max_len=128, share: dict | None = None,
                # ---- deprecated store-calling-convention knobs (None
                # sentinels so misuse alongside a service is detectable) ----
                l1=None, l2=None, limiter=None, fetch_limiter=None,
@@ -60,6 +61,11 @@ def cold_start(model, manifest_blob: bytes, tenant_key: bytes, service, *,
     dtypes (float32/bf16-as-uint16/int8) pass through untouched. The
     tree is then placed on the default device, and ``load_seconds``
     ends once it is resident there (``block_until_ready``).
+
+    `share` ({path: dim slices in the image's tensor | None}, as
+    ``model.param_share()`` states it) restores only those slices,
+    through ``ImageHandle.restore_share`` under the same policy and
+    decoder; ``stats["share"]`` then holds its numbers.
 
     Deprecation path: passing a raw chunk store as `service` (with the
     old l1/l2/limiter/fetch_limiter/batched/streamed/parallelism/decoder
@@ -90,14 +96,15 @@ def cold_start(model, manifest_blob: bytes, tenant_key: bytes, service, *,
     try:
         return _cold_start_admitted(model, manifest_blob, tenant_key,
                                     service, root, tenant, policy,
-                                    max_batch, max_len, decoder)
+                                    max_batch, max_len, decoder, share)
     finally:
         if private_service is not None:
             private_service.close()
 
 
 def _cold_start_admitted(model, manifest_blob, tenant_key, service, root,
-                         tenant, policy, max_batch, max_len, decoder):
+                         tenant, policy, max_batch, max_len, decoder,
+                         share):
     with contextlib.ExitStack() as stack:
         stack.enter_context(request_scope())
         top = stack.enter_context(span("repro.coldstart"))
@@ -115,7 +122,11 @@ def _cold_start_admitted(model, manifest_blob, tenant_key, service, root,
         # this replica's stats
         before_origin = handle.counters.get("read.origin_fetches")
         template = model.param_shapes()
-        flat = handle.restore_tree(policy=policy)
+        held = None
+        if share is None:
+            flat = handle.restore_tree(policy=policy)
+        else:
+            flat, held = handle.restore_share(share, policy=policy)
         with span("repro.restore.assemble"):
             # promoted on the host, so the device holds the serving dtype
             params = jax.tree.map(
@@ -155,35 +166,7 @@ def _cold_start_admitted(model, manifest_blob, tenant_key, service, root,
             "eager_flushes": lb.get("eager_flushes"),
             "decode_tiles": lb.get("decode_tiles"),
             "tiles_overlapped": lb.get("tiles_overlapped"),
+            "share": held,
         }
         return engine, stats
 
-
-def expert_shard_restore(reader, num_experts: int,
-                         ep_rank: int, ep_size: int,
-                         parallelism: int = DEFAULT_PARALLELISM,
-                         policy: ReadPolicy | None = None) -> dict:
-    """Restore only this worker's expert slices (plus all non-expert
-    tensors): the EP sparsity path. Returns {name: array-or-shard}.
-
-    `reader` is an ``ImageHandle`` (or the deprecated ``ImageReader``
-    shim). All tensors' byte ranges go into a single batched
-    ``restore_shards`` call under `policy` (default: a streamed policy
-    at `parallelism` — before the redesign this path silently ignored
-    the pipeline knobs and always used staged defaults)."""
-    if policy is None:
-        policy = ReadPolicy(parallelism=parallelism)
-    lo = num_experts * ep_rank // ep_size
-    hi = num_experts * (ep_rank + 1) // ep_size
-    shard_slices = {}
-    for name in reader.tensor_names():
-        t = reader.layout.tensors[name]
-        edim = next((i for i, d in enumerate(t.shape)
-                     if d == num_experts and len(t.shape) >= 3), None)
-        if edim is None:
-            shard_slices[name] = None
-        else:
-            sl = [(0, d) for d in t.shape]
-            sl[edim] = (lo, hi)
-            shard_slices[name] = sl
-    return reader.restore_shards(shard_slices, policy=policy)

@@ -24,6 +24,7 @@ class Request:
     max_new: int = 16
     out: list = field(default_factory=list)
     done: bool = False
+    first_logits: np.ndarray | None = None   # the step that gave out[0]
 
 
 class ServeEngine:
@@ -75,7 +76,10 @@ class ServeEngine:
             if req._feed:                        # still consuming the prompt
                 self.tokens[s] = req._feed.pop(0)
                 continue
-            nxt = int(np.argmax(logits[s, :self.model.cfg.vocab_size]))
+            row = logits[s, :self.model.cfg.vocab_size]
+            if not req.out:
+                req.first_logits = row
+            nxt = int(np.argmax(row))
             req.out.append(nxt)
             self.tokens[s] = nxt
             if len(req.out) >= req.max_new or self.pos[s] >= self.max_len - 1:

@@ -1,6 +1,8 @@
 """Serving cold-start + the full end-to-end system test (deliverable b/c):
 train -> checkpoint to chunk store -> corrupt/fail infrastructure ->
 cold-start serve through the cache tiers -> generate."""
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
@@ -13,7 +15,8 @@ from repro.core.gc import GenerationalGC
 from repro.core.loader import ImageReader, create_image
 from repro.core.store import ChunkStore
 from repro.models import build_model
-from repro.serve.coldstart import cold_start, expert_shard_restore
+from repro.core.service import ImageService, ServiceConfig
+from repro.serve.coldstart import cold_start
 from repro.serve.engine import Request, ServeEngine
 from repro.train.checkpoint import CheckpointManager
 from repro.train.loop import LoopConfig, Trainer
@@ -61,25 +64,37 @@ def test_concurrency_limiter_rejects():
 
 
 def test_expert_shard_restore(tmp_path):
-    cfg = get_config("arctic-480b").reduced()
-    m = build_model(cfg)
-    params = m.init(jax.random.key(0))
+    """A replica holding expert-parallel rank 1 of 2 restores, through
+    ``cold_start(share=...)``, exactly its experts' slice of each expert
+    leaf (the axis the specs name "expert", axis 1 of the stacked
+    (L, E, ...) leaves) and every other leaf whole."""
+    full_cfg = get_config("arctic-480b").reduced()
+    cfg = dataclasses.replace(full_cfg, ep_size=2, ep_rank=1)
     from repro.train.checkpoint import state_to_tree
-    tree = state_to_tree(params)
+    tree = state_to_tree(build_model(full_cfg).init(jax.random.key(0)))
     store = ChunkStore(tmp_path / "s")
     gc = GenerationalGC(store)
     blob, _ = create_image(tree, tenant="t", tenant_key=b"E" * 32, store=store,
                            root=gc.active, chunk_size=4096)
-    reader = ImageReader(blob, b"E" * 32, store)
-    shard = expert_shard_restore(reader, cfg.num_experts, ep_rank=1, ep_size=2)
-    # expert tensors halved, others full
-    for name, arr in shard.items():
-        full = tree[name]
-        if cfg.num_experts in full.shape and full.ndim >= 3:
-            assert arr.shape[1] == full.shape[1] // 2 or \
-                arr.shape[0] == full.shape[0]  # stacked (L, E, ...)
+    m = build_model(cfg)
+    share = m.param_share()
+    engine, stats = cold_start(m, blob, b"E" * 32,
+                               ImageService(store, ServiceConfig()),
+                               root=gc.active, share=share)
+    got = state_to_tree(engine.params)
+    lo, hi = cfg.held_experts
+    assert (lo, hi) == (cfg.num_experts // 2, cfg.num_experts)
+    sliced = [n for n, sl in share.items() if sl is not None]
+    assert sorted(sliced) == sorted(n for n in tree if n.split("/")[-1] in
+                                    ("w_gate", "w_up", "w_down")
+                                    and "/ffn/" in n and "residual" not in n)
+    for name, arr in got.items():
+        if name in sliced:
+            assert arr.shape[1] == tree[name].shape[1] // 2
+            np.testing.assert_array_equal(arr, tree[name][:, lo:hi])
         else:
-            assert arr.shape == full.shape
+            np.testing.assert_array_equal(arr, tree[name])
+    assert stats["share"]["held_bytes"] == sum(a.nbytes for a in got.values())
 
 
 class TestEndToEnd:

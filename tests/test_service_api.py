@@ -2,7 +2,7 @@
 concurrency over one shared service (byte identity, cross-tenant dedup
 in scoped telemetry, process-wide single-flight), admission control
 under real concurrency, the idle-queue eager flush, policy plumbing
-through prefetch / expert_shard_restore, the float32 serving-dtype cast,
+through prefetch / restore_share, the float32 serving-dtype cast,
 and the ImageReader deprecation shim's equivalence."""
 import threading
 import time
@@ -24,7 +24,7 @@ from repro.core.service import (
 )
 from repro.core.store import ChunkStore
 from repro.core.telemetry import COUNTERS, Counters
-from repro.serve.coldstart import cold_start, expert_shard_restore
+from repro.serve.coldstart import cold_start
 
 CS = 4096
 
@@ -411,28 +411,93 @@ def test_prefetch_streamed_policy_warms_tiers(tmp_path):
         assert np.array_equal(flat[n], np.asarray(tree[n]))
 
 
-def test_expert_shard_restore_policy_plumbs(tmp_path):
+def _expert_image(tmp_path):
+    """A 2-layer stack of 4 experts (6 KiB rows) and a dense leaf."""
     store = ChunkStore(tmp_path / "s")
     gc = GenerationalGC(store)
     rng = np.random.default_rng(5)
-    ne = 4
-    tree = {"moe/experts": rng.standard_normal((2, ne, 64)).astype(np.float32),
+    tree = {"moe/experts": rng.standard_normal((2, 4, 1536)).astype(np.float32),
             "dense/w": rng.standard_normal((32, 8)).astype(np.float32)}
     blob, _ = create_image(tree, tenant="t", tenant_key=b"X" * 32,
                            store=store, root=gc.active, chunk_size=CS)
+    return store, blob, tree
+
+
+def test_expert_shard_restore_policy_plumbs(tmp_path):
+    """``restore_share`` reads the share under every policy and counts
+    the chunks it fetches, those its ranges lie in, and those it holds
+    only part of."""
+    store, blob, tree = _expert_image(tmp_path)
     svc = ImageService(store, ServiceConfig(l1_bytes=8 << 20, l2_nodes=0))
     h = svc.open(blob, b"X" * 32)
+    share = {"moe/experts": [(0, 2), (1, 3), (0, 1536)], "dense/w": None}
     for pol in (None, ReadPolicy(mode="staged"), ReadPolicy(mode="serial")):
-        shard = expert_shard_restore(h, ne, ep_rank=1, ep_size=2, policy=pol)
-        assert np.array_equal(shard["moe/experts"],
-                              np.asarray(tree["moe/experts"])[:, 2:4])
-        assert np.array_equal(shard["dense/w"], np.asarray(tree["dense/w"]))
-    # the deprecated ImageReader shim takes the same policy keyword
-    shard = expert_shard_restore(ImageReader(blob, b"X" * 32, store), ne,
-                                 ep_rank=0, ep_size=2,
-                                 policy=ReadPolicy(mode="staged"))
-    assert np.array_equal(shard["moe/experts"],
+        got, numbers = h.restore_share(share, policy=pol)
+        assert np.array_equal(got["moe/experts"],
+                              np.asarray(tree["moe/experts"])[:, 1:3])
+        assert np.array_equal(got["dense/w"], np.asarray(tree["dense/w"]))
+        # an expert row is 6 KiB: rows 1-2 of a layer span 4 chunks, the
+        # first and last of which also hold bytes of rows 0 and 3
+        assert numbers == {"held_bytes": 2 * 2 * 6144 + 32 * 8 * 4,
+                           "image_bytes": 2 * 4 * 6144 + 32 * 8 * 4,
+                           "chunks": 2 * 4 + 1, "chunks_covered": 2 * 4 + 1,
+                           "partial_chunks": 2 * 2}
+    assert h.counters.get("restore.share_bytes") == 3 * (2 * 2 * 6144
+                                                         + 32 * 8 * 4)
+    assert h.counters.get("restore.skipped_bytes") == 3 * 2 * 2 * 6144
+
+
+@pytest.mark.parametrize("mode", ["streamed", "staged", "serial"])
+def test_share_chunks_are_what_the_read_fetched(tmp_path, mode):
+    """``chunks`` is counted by the read, not the layout: two layers of
+    equal bytes are one set of names, fetched once (the serial oracle
+    fetches a chunk per range it lies in); and a read that fetches more
+    than the share's ranges counts more, where ``chunks_covered`` does
+    not move."""
+    store = ChunkStore(tmp_path / "s")
+    gc = GenerationalGC(store)
+    layer = np.random.default_rng(6).standard_normal((4, 1536))
+    tree = {"moe/experts": np.stack([layer, layer]).astype(np.float32)}
+    blob, _ = create_image(tree, tenant="t", tenant_key=b"X" * 32,
+                           store=store, root=gc.active, chunk_size=CS)
+    h = ImageService(store, ServiceConfig(l1_bytes=0, l2_nodes=0)).open(
+        blob, b"X" * 32)
+    share = {"moe/experts": [(0, 2), (1, 3), (0, 1536)]}
+    policy = ReadPolicy(mode=mode)
+    _, numbers = h.restore_share(share, policy=policy)
+    assert numbers["chunks_covered"] == 2 * 4
+    assert numbers["chunks"] == (2 * 4 if mode == "serial" else 4)
+    if mode == "serial":
+        return
+    read_many = h.reader.read_many
+
+    def whole_image_too(ranges, *args, **kw):
+        ranges = list(ranges)
+        everything = (0, h.layout.num_chunks * CS)
+        return read_many(ranges + [everything], *args, **kw)[:len(ranges)]
+    h.reader.read_many = whole_image_too
+    _, more = h.restore_share(share, policy=policy)
+    assert more["chunks_covered"] == numbers["chunks_covered"]
+    assert more["chunks"] == 6 > numbers["chunks"]
+
+
+def test_share_through_the_legacy_cold_start(tmp_path):
+    """The deprecated raw-store calling convention of ``cold_start``
+    restores a share under its legacy knobs."""
+    store, blob, tree = _expert_image(tmp_path)
+    model = _TinyModel(jax.eval_shape(
+        lambda: {"moe": {"experts": np.zeros((2, 2, 1536), np.float32)},
+                 "dense": {"w": np.zeros((32, 8), np.float32)}}))
+    eng, stats = cold_start(
+        model, blob, b"X" * 32, store, batched=True, streamed=False,
+        share={"moe/experts": [(0, 2), (0, 2), (0, 1536)], "dense/w": None},
+        max_batch=1, max_len=8)
+    assert np.array_equal(np.asarray(eng.params["moe"]["experts"]),
                           np.asarray(tree["moe/experts"])[:, 0:2])
+    assert np.array_equal(np.asarray(eng.params["dense"]["w"]),
+                          np.asarray(tree["dense/w"]))
+    assert stats["streamed"] is False
+    assert stats["share"]["held_bytes"] == 2 * 2 * 6144 + 32 * 8 * 4
 
 
 # ------------------------------------------------------ scoped telemetry
