@@ -4,9 +4,10 @@ For each ``lanes x chunk_bytes`` shape it decodes ``lanes`` random
 chunks, each under its own random AES-256 key, through
 ``fused_verify_decrypt`` and reports two host-clock times per tile:
 
-* ``call_ms``: the jitted device program alone (word swap + transpose,
-  the fused kernel, the inverse), inputs already on the device, timed
-  to ``block_until_ready``; median of ``REPEATS``;
+* ``call_ms``: the jitted device program alone (the keystream kernel
+  and its transpose, the word swap and transpose, the SHA walk), inputs
+  already on the device, timed to ``block_until_ready``; median of
+  ``REPEATS``;
 * ``adapter_ms``: the whole adapter, host marshalling and the copy of
   plaintext back to the host included; median of ``REPEATS``.
 
@@ -74,7 +75,7 @@ def probe(lanes: int, chunk: int, rng) -> dict:
 
     def call():
         jax.block_until_ready(_fused_device(
-            *args, rounds=rk.shape[0] - 1, pallas=pallas, interpret=False))
+            *args, rounds=rk.shape[1] - 1, pallas=pallas, interpret=False))
 
     call()
     call_ms = _median_ms(call)

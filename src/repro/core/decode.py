@@ -74,12 +74,12 @@ instead of threading ``encrypt_many``/``sha_backend`` hooks separately:
   lockstep SHA-256 verify (``kernels/sha256``). The TPU VPU lowering;
   off-TPU both kernels run under the Pallas interpreter (what the CPU
   tests drive; on TPU a kernel compiles or raises, never interprets).
-* ``"bitsliced-fused"`` (alias ``"fused"``): ONE tiled pass
-  (``kernels/fused``) producing digests AND plaintext from a single
-  walk over each ciphertext — the lockstep SHA lanes and the bitsliced
-  keystream XOR share the tile, halving memory traffic versus the
-  ``sha_many``-then-``encrypt_many`` pair, with per-CHUNK round keys
-  broadcast inside the kernel instead of repeated per block.
+* ``"bitsliced-fused"`` (alias ``"fused"``): ONE device program per
+  tile (``kernels/fused``) producing digests AND plaintext: a
+  lane-dense bitsliced keystream pass with per-CHUNK round keys, then
+  one lockstep SHA walk over each ciphertext that XORs the keystream
+  in — one dispatch and one readback, where the
+  ``sha_many``-then-``encrypt_many`` pair takes two of each.
 * ``"auto"`` (the ``ServiceConfig`` default): probe the jax platform —
   ``bitsliced-fused`` on TPU, ``xla`` on GPU, ``python`` on CPU.
 * ``"serial"``: the per-chunk ``decrypt_chunk`` oracle — PR 1's caller-
@@ -278,9 +278,9 @@ register_backend(DecodeBackend(
     "(Boyar-Peralta S-box circuit) + lockstep SHA-256 verify (TPU VPU; "
     "Pallas interpreter off-TPU)", threads=1, loader=_load_bitsliced))
 register_backend(DecodeBackend(
-    "bitsliced-fused", "ONE fused pass: lockstep SHA-256 digests + "
-    "bitsliced AES-CTR keystream XOR from a single walk over each "
-    "ciphertext tile, per-chunk round keys broadcast in-kernel "
+    "bitsliced-fused", "ONE device program per tile: a lane-dense "
+    "bitsliced AES-CTR keystream pass, then a lockstep SHA-256 walk that "
+    "XORs it into the ciphertext, per-chunk round keys "
     "(kernels/fused; Pallas on TPU, whole-batch XLA jit elsewhere)",
     threads=1, loader=_load_fused), aliases=("fused",))
 

@@ -1,36 +1,36 @@
-"""Fused verify+decrypt: one tiled pass over each ciphertext tile.
+"""Fused verify+decrypt: a lane-dense keystream pass, then one SHA walk.
 
-The two-pass decode (``sha256_many_pallas`` then the bitsliced
-keystream) streams every ciphertext byte through the device twice —
-once as SHA schedule words, once as AES state planes — and pays two
-host round-trips. This module runs BOTH in one pass over one layout:
+A decode tile is N chunks, one per lane of a zeroed, SHA-padded byte
+buffer ``buf`` (lanes, maxb*16) of native int32 words (``sha256.ops.
+pack_messages``). The device program has two phases, both in one jit:
 
-* the tile arrives exactly like the SHA kernel's input — padded
-  schedule words, word-major (16, maxb, lanes) int32, one chunk per
-  lane — and the lockstep compression (``sha256p.sha_fold``) folds it
-  to per-lane digests;
-* the grid is (lane tiles, block steps), like the SHA kernel's: a step
-  is ``STEP`` = 8 message blocks = 32 AES blocks, so a 512 KiB chunk is
-  1032 steps of a (16, 8, lanes) tile, and the digest state stays
-  resident in the digest output block across steps;
-* each step's AES-CTR keystream comes from the bitsliced circuit
-  (``bitslice.encrypt_planes_body``, the AES kernel's body) over (16,
-  lanes) planes with lane = chunk: the 32 bits of a plane word are the
-  step's 32 counter blocks of that ONE chunk. So the per-chunk round
-  keys are plain 0/-1 words, the counter planes are (16, 1) columns of
-  constants and of the step's first block ``m0``, and bit ``k`` of a
-  plane word — AES block ``m0 + k`` = word ``4*(k % 4) + w4`` of the
-  step's message block ``k // 4`` — unpacks with static shifts: no lane
-  shuffle anywhere;
-* the keystream words XOR into the ciphertext words in-register:
-  plaintext comes back in the same layout the digests were computed
-  from. One device visit per ciphertext byte.
+1. ``aes_bitsliced`` (``keystream_pallas``): the zero-IV AES-CTR
+   keystream of every lane, computed apart from the SHA chain. The
+   bitsliced circuit (``bitslice.encrypt_planes_body``, the AES
+   kernel's body) runs over (16, tile) planes whose lane WORDS are
+   counter groups of one chunk: bit ``k`` of lane word ``g`` is AES
+   block ``32*g + k``, so the planes are full whatever the tile's chunk
+   count. The grid is (chunks, word tiles); a step reads its chunk's
+   (rounds+1, 16, 8) key planes through the index map, makes its counter
+   planes from iota (no counter bytes cross to the device), and skips
+   itself when its blocks lie past the chunk's end. Each step unpacks
+   its planes into rows ``4*k + w4`` (native word ``w4`` of block
+   ``32*g + k``), so one XLA transpose of a (128, words) slab per chunk
+   gives the keystream in ``buf``'s own layout (``native_keystream``).
+2. ``fused_verify_decrypt`` (``walk_pallas``): the serial SHA-256 walk.
+   The grid is (lane tiles, block steps) of ``sha256p.STEP_BLOCKS``
+   blocks as in ``sha256_lanes``, over the word-major (16, maxb, lanes)
+   schedule words, the digest state resident in the digest block across
+   steps; each step folds its blocks through ``sha256p.sha_fold`` and
+   XORs the step's keystream into the ciphertext words of ``buf``.
+   Plaintext leaves in ``buf``'s layout; past each chunk's length it is
+   garbage that the host never reads.
 
-A Pallas kernel (the TPU route) and a pure-jnp jit (the XLA route, all
-steps at once along an extra group axis) share every traced helper, so
-kernel == jit == two-pass oracles by construction. Tamper detection
-stays per-chunk: the host adapter (``ops``) compares digests before
-releasing any plaintext.
+A Pallas route (the TPU's) and a pure-jnp jit (the XLA route: the
+keystream of every word at once, then the fold and the XOR) share
+every traced helper, so kernel == jit == two-pass oracles by
+construction. Tamper detection stays per chunk: the host adapter
+(``ops``) compares digests before releasing any plaintext.
 """
 from __future__ import annotations
 
@@ -41,7 +41,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.aes.bitslice import encrypt_planes_body
+from repro.kernels.aes.bitslice import _permute_rows, encrypt_planes_body
+from repro.kernels.sha256.ops import words_from_bytes
 from repro.kernels.sha256.sha256p import (
     LANE_BLOCK,
     sha_fold,
@@ -49,19 +50,22 @@ from repro.kernels.sha256.sha256p import (
     tile_shape,
 )
 
-STEP = 8                   # message blocks per step: 32 AES blocks
+KS_TILE = 128              # keystream lane words per step: 4096 AES blocks
 
 _SHL = jax.lax.shift_left
 _SRL = jax.lax.shift_right_logical
 # bit e < 5 of counter m0 + k over the 32 lanes k of a word (m0 % 32 == 0)
 _LOW_PATTERNS = [sum(((k >> e) & 1) << k for k in range(32)) - (1 << 32)
                  for e in range(5)]
+# plane rows p = 4*w4 + q reordered to 4*q + w4: byte q of every native
+# word w4 of a block in one 4-row slab
+_QW_ROWS = [4 * (r % 4) + r // 4 for r in range(16)]
 
 
 def _counter_planes(m0, shape: tuple) -> list:
     """8 counter bit planes shaped `shape` (positions on axis 0): plane
     ``i`` row ``p`` is bit ``8*(15-p) + i`` of the zero-IV CTR counter
-    ``m0 + k`` in bit ``k`` of the word. `m0` (a multiple of 32, below
+    ``m0 + k`` in bit ``k`` of the word. `m0` (multiples of 32, below
     2**31) broadcasts against `shape`."""
     e0 = 8 * (15 - jax.lax.broadcasted_iota(jnp.int32, shape, 0))
     planes = []
@@ -74,31 +78,91 @@ def _counter_planes(m0, shape: tuple) -> list:
     return planes
 
 
-def keystream_words(m0, shape: tuple, rk_at, rounds: int) -> list:
-    """AES-CTR keystream of 32 consecutive counter blocks from `m0` for
-    every lane, as 4 arrays ``words[w4]`` of shape (32, 1) + shape[1:]:
-    ``words[w4][k]`` is big-endian schedule word ``w4`` of AES block
-    ``m0 + k``. ``rk_at(r)`` gives round ``r``'s (8, 16, ...) 0/-1 key
-    planes."""
-    planes = encrypt_planes_body(_counter_planes(m0, shape), rk_at, rounds)
-    kk = jax.lax.broadcasted_iota(jnp.int32, (32,) + (1,) * len(shape), 0)
-    byt = None
-    for i, pl_i in enumerate(planes):
-        bit = _SHL(_SRL(pl_i[None], kk) & 1, i)
-        byt = bit if byt is None else byt | bit       # (32, 16, ...)
-    words = []
-    for w4 in range(4):
-        word = None
-        for j in range(4):
-            p = 4 * w4 + j
-            b = _SHL(byt[:, p:p + 1], 24 - 8 * j)
-            word = b if word is None else word | b
-        words.append(word)
-    return words
+def keystream_rows(g, rk_at, rounds: int):
+    """AES-CTR keystream of the counter groups `g` (int32 lane words,
+    any shape ``lanes``): (128,) + lanes int32, row ``4*k + w4`` the
+    native word ``w4`` (stream bytes ``4*w4 .. 4*w4+3``, first in the
+    low byte) of AES block ``32*g + k``. ``rk_at(r)`` gives round
+    ``r``'s key planes as 8 arrays (plane ``i``) that broadcast against
+    (16,) + lanes."""
+    shape = (16,) + g.shape
+    planes = encrypt_planes_body(_counter_planes(32 * g[None], shape),
+                                 rk_at, rounds)
+    planes = [_permute_rows(x, _QW_ROWS, jnp) for x in planes]
+    q8 = 8 * (jax.lax.broadcasted_iota(
+        jnp.int32, (16,) + (1,) * g.ndim, 0) // 4)
+    rows = []
+    for k in range(32):
+        v = None
+        for i, x in enumerate(planes):
+            b = _SHL(_SRL(x, k) & 1, q8 + i)
+            v = b if v is None else v | b
+        rows.append(v[0:4] | v[4:8] | v[8:12] | v[12:16])
+    return jnp.concatenate(rows, axis=0)
 
 
-def _fused_kernel(words_ref, nb_ref, rk_ref, dig_ref, out_ref, *,
-                  rounds: int):
+def native_keystream(ks):
+    """(N, 128, words) keystream rows -> (N, words*128) in the layout of
+    the byte buffer: native word ``128*g + r`` is row ``r`` of word
+    ``g``."""
+    n, _, words = ks.shape
+    return ks.transpose(0, 2, 1).reshape(n, words * 128)
+
+
+def _ks_tile(maxb: int, tile: int) -> tuple:
+    """(keystream lane words per lane, words per step) for a buffer of
+    ``maxb`` message blocks: ``maxb/8`` words (32 AES blocks each) in
+    one step, or rounded up to whole steps of `tile` words."""
+    words = maxb // 8
+    if words <= tile:
+        return words, words
+    return -(-words // tile) * tile, tile
+
+
+def _keystream_kernel(nb_ref, rk_ref, out_ref, *, rounds: int, tile: int):
+    c, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(8 * tile * j < nb_ref[c])        # steps past the chunk skip
+    def _():
+        def rk_at(r):
+            k = rk_ref[r]
+            return [k[:, i:i + 1] for i in range(8)]
+
+        g = tile * j + jax.lax.broadcasted_iota(jnp.int32, (tile,), 0)
+        out_ref[...] = keystream_rows(g, rk_at, rounds)
+
+
+@functools.partial(jax.jit, static_argnames=("maxb", "rounds", "interpret",
+                                             "tile"))
+def keystream_pallas(nblocks, rk, *, maxb: int, rounds: int,
+                     interpret: bool = False, tile: int = KS_TILE):
+    """Pallas launch of the keystream pass: nblocks (1, N) int32 message
+    blocks per lane (0 for an empty lane, whose steps all skip), rk (N,
+    rounds+1, 16, 8) int32 per-chunk 0/-1 key planes -> (N, 128, words)
+    int32 keystream rows (``keystream_rows``), words covering ``maxb``
+    message blocks; rows of skipped steps are left unwritten."""
+    n = nblocks.shape[1]
+    words, tile = _ks_tile(maxb, tile)
+    return pl.pallas_call(
+        functools.partial(_keystream_kernel, rounds=rounds, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(n, words // tile),
+            in_specs=[pl.BlockSpec((None, rounds + 1, 16, 8),
+                                   lambda c, j, nb: (c, 0, 0, 0))],
+            out_specs=pl.BlockSpec((None, 128, tile),
+                                   lambda c, j, nb: (c, 0, j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, 128, words), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+        name="aes_bitsliced",
+    )(nblocks.reshape(n), rk)
+
+
+def _walk_kernel(words_ref, nb_ref, ct_ref, ks_ref, dig_ref, out_ref, *,
+                 bt: int):
     k = pl.program_id(1)
     nb = nb_ref[...]
 
@@ -110,65 +174,74 @@ def _fused_kernel(words_ref, nb_ref, rk_ref, dig_ref, out_ref, *,
     state = tuple(dig_ref[i:i + 1, :] for i in range(8))
     state = sha_fold(
         lambda j: [words_ref[t, pl.ds(j, 1), :] for t in range(16)],
-        nb, state, k * STEP, STEP)
+        nb, state, k * bt, bt)
     for i in range(8):
         dig_ref[i:i + 1, :] = state[i]
-    ks = keystream_words(k * (4 * STEP), (16, 1), lambda r: rk_ref[r],
-                         rounds)
-    for b in range(STEP):
-        for t in range(16):
-            out_ref[t, b:b + 1, :] = (words_ref[t, b:b + 1, :]
-                                      ^ ks[t % 4][4 * b + t // 4])
+    out_ref[...] = ct_ref[...] ^ ks_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("rounds", "interpret", "block"))
-def fused_lanes_pallas(words, nblocks, rk_planes, *, rounds: int,
-                       interpret: bool = False, block: int = LANE_BLOCK):
-    """Pallas launch: words (16, maxb, N) int32, nblocks (1, N) int32,
-    rk_planes (rounds+1, 8, 16, N) int32 per-chunk 0/-1 key planes ->
-    (digests (8, N) int32, plaintext words (16, maxb, N) int32). ``maxb``
-    is a multiple of ``STEP``; lanes tile as ``sha256p.tile_shape``."""
+@functools.partial(jax.jit, static_argnames=("interpret", "block"))
+def walk_pallas(words, nblocks, ct, ks, *, interpret: bool = False,
+                block: int = LANE_BLOCK):
+    """Pallas launch of the SHA walk + XOR: words (16, maxb, N) int32
+    schedule words, nblocks (1, N), ct (N, maxb*16) the same bytes as
+    native int32 words, ks (N, >= maxb*16) keystream in ct's layout ->
+    (digests (8, N) int32, plaintext (N, maxb*16) int32 in ct's layout).
+    Steps and lane tiles as ``sha256p.tile_shape``."""
     _, maxb, n = words.shape
-    _, blk = tile_shape(maxb, n, block)
+    bt, blk = tile_shape(maxb, n, block)
+    row = pl.BlockSpec((blk, 16 * bt), lambda i, k: (i, k))
     return pl.pallas_call(
-        functools.partial(_fused_kernel, rounds=rounds),
-        grid=(n // blk, maxb // STEP),
+        functools.partial(_walk_kernel, bt=bt),
+        grid=(n // blk, maxb // bt),
         in_specs=[
-            pl.BlockSpec((16, STEP, blk), lambda i, k: (0, k, i)),
+            pl.BlockSpec((16, bt, blk), lambda i, k: (0, k, i)),
             pl.BlockSpec((1, blk), lambda i, k: (0, i)),
-            pl.BlockSpec((rounds + 1, 8, 16, blk), lambda i, k: (0, 0, 0, i)),
+            row,
+            row,
         ],
-        out_specs=(
-            pl.BlockSpec((8, blk), lambda i, k: (0, i)),
-            pl.BlockSpec((16, STEP, blk), lambda i, k: (0, k, i)),
-        ),
+        out_specs=(pl.BlockSpec((8, blk), lambda i, k: (0, i)), row),
         out_shape=(
             jax.ShapeDtypeStruct((8, n), jnp.int32),
-            jax.ShapeDtypeStruct((16, maxb, n), jnp.int32),
+            jax.ShapeDtypeStruct(ct.shape, jnp.int32),
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="fused_verify_decrypt",
-    )(words, nblocks, rk_planes)
+    )(words, nblocks, ct, ks)
+
+
+@functools.partial(jax.jit, static_argnames=("rounds", "interpret", "block"))
+def fused_lanes_pallas(buf, nblocks, rk, *, rounds: int,
+                       interpret: bool = False, block: int = LANE_BLOCK):
+    """The Pallas route: buf (N, maxb*16) int32 native words of the
+    SHA-padded chunks, nblocks (1, N) int32, rk (N, rounds+1, 16, 8)
+    int32 -> (digests (8, N) int32, plaintext (N, maxb*16) int32 in
+    buf's layout): the keystream pass, its transpose to buf's layout,
+    then the walk."""
+    words = words_from_bytes(buf)
+    ks = keystream_pallas(nblocks, rk, maxb=words.shape[1], rounds=rounds,
+                          interpret=interpret)
+    return walk_pallas(words, nblocks, buf, native_keystream(ks),
+                       interpret=interpret, block=block)
 
 
 @functools.partial(jax.jit, static_argnames=("rounds",))
-def fused_lanes_jit(words, nblocks, rk_planes, *, rounds: int):
-    """The same fused pass as ONE XLA program over the whole tile: the
-    SHA fold walks all blocks, and the keystream of every step is one
-    plane pipeline along an extra group axis. The off-TPU route."""
-    _, maxb, n = words.shape
-    groups = maxb // STEP
+def fused_lanes_jit(buf, nblocks, rk, *, rounds: int):
+    """The same two phases as ONE XLA program over the whole tile (the
+    off-TPU route): the keystream of every lane word of every lane at
+    once, the SHA fold over all blocks, then the XOR."""
+    n, width = buf.shape
+    words = words_from_bytes(buf)
+
+    def rk_at(r):
+        k = jax.lax.dynamic_index_in_dim(rk, r, 1, keepdims=False)
+        return [k[:, :, i].T[:, :, None] for i in range(8)]
+
+    g = jax.lax.broadcasted_iota(jnp.int32, (n, width // 128), 1)
+    ks = keystream_rows(g, rk_at, rounds).transpose(1, 0, 2)
     state = sha_fold(
         lambda j: list(jax.lax.dynamic_slice_in_dim(words, j, 1, 1)),
-        nblocks, sha_init(nblocks), 0, maxb)
-    m0 = 4 * STEP * jax.lax.broadcasted_iota(jnp.int32, (1, groups, 1), 1)
-    ks = keystream_words(m0, (16, groups, 1),
-                         lambda r: rk_planes[r][:, :, None, :], rounds)
-    plain = []
-    for t in range(16):
-        q, w4 = t // 4, t % 4
-        kt = ks[w4][q::4, 0]                  # (STEP, groups, n): block b
-        plain.append(words[t] ^ kt.transpose(1, 0, 2).reshape(maxb, n))
-    return jnp.concatenate(state, axis=0), jnp.stack(plain)
+        nblocks, sha_init(nblocks), 0, words.shape[1])
+    return jnp.concatenate(state, axis=0), buf ^ native_keystream(ks)

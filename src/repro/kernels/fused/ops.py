@@ -17,11 +17,17 @@ device busy while the host marshals (``core.decode``'s tile loop does);
 offers ``submit`` as its attribute.
 
 Marshalling is the SHA kernel's (``sha256.ops.pack_messages``): one
-padded byte row per chunk, word swap and transpose on the device. The
-round keys travel as per-chunk 0/-1 bit planes (``round_key_planes``).
-Each tile is four ``repro.kernel.*`` spans (pack and dispatch in
-``submit``, readback and split in ``result``) and adds its copies to the
-``xfer.*`` counters.
+padded byte row per chunk. The round keys travel as one schedule of
+0/-1 bit planes per chunk (``round_key_planes``); no counter or
+per-block key bytes cross. ``_fused_device`` is one jit, one dispatch,
+of two Pallas kernels on the TPU (``fusedp``): ``aes_bitsliced``, the
+lane-dense keystream pass, then ``fused_verify_decrypt``, the SHA walk
+that XORs that keystream into the ciphertext words; off the TPU the
+same two phases as one XLA program. Plaintext comes back in the byte
+buffer's own layout. Each tile is four ``repro.kernel.*`` spans (pack
+and dispatch in ``submit``, readback and split in ``result``), one
+``kernel.route.fused.*`` launch, and adds its copies to the ``xfer.*``
+counters.
 """
 from __future__ import annotations
 
@@ -34,38 +40,31 @@ from repro.core.crypto.aes import expand_key
 from repro.core.telemetry import COUNTERS, D2H_BYTES, H2D_BYTES, span
 from repro.kernels import on_tpu, pallas_interpret, record_route
 from repro.kernels.fused.fusedp import fused_lanes_jit, fused_lanes_pallas
-from repro.kernels.sha256.ops import (
-    bytes_from_words,
-    digests_to_bytes,
-    pack_messages,
-    words_from_bytes,
-)
+from repro.kernels.sha256.ops import digests_to_bytes, pack_messages
 
 
 def round_key_planes(keys: list, lanes: int) -> np.ndarray:
-    """AES key schedules of N chunk keys -> (rounds+1, 8, 16, lanes)
-    int32: ``[r, i, p]`` is -(bit i of round ``r``'s key byte p), one
-    lane per chunk (lanes past N are zero)."""
+    """AES key schedules of N chunk keys -> (lanes, rounds+1, 16, 8)
+    int32: ``[c, r, p, i]`` is -(bit i of round ``r``'s key byte p) of
+    chunk ``c`` (lanes past N are zero): one schedule per chunk, the
+    keystream pass picks each step's by its index map."""
     expanded = {k: expand_key(k) for k in set(keys)}
     rks = np.stack([expanded[k] for k in keys])           # (N, R+1, 4)
     n, nr, _ = rks.shape
     kb = rks.astype(">u4").view(np.uint8).reshape(n, nr, 16)   # byte p
     bits = np.unpackbits(kb[..., None], axis=-1, bitorder="little")
-    planes = np.zeros((nr, 8, 16, lanes), np.int32)
-    planes[..., :n] = -bits.transpose(1, 3, 2, 0).astype(np.int32)
+    planes = np.zeros((lanes, nr, 16, 8), np.int32)
+    planes[:n] = -bits.astype(np.int32)
     return planes
 
 
 @functools.partial(jax.jit, static_argnames=("rounds", "pallas", "interpret"))
 def _fused_device(buf, nb, rk_planes, *, rounds: int, pallas: bool,
                   interpret: bool):
-    words = words_from_bytes(buf)
     if pallas:
-        dig, plain = fused_lanes_pallas(words, nb, rk_planes, rounds=rounds,
-                                        interpret=interpret)
-    else:
-        dig, plain = fused_lanes_jit(words, nb, rk_planes, rounds=rounds)
-    return dig, bytes_from_words(plain)
+        return fused_lanes_pallas(buf, nb, rk_planes, rounds=rounds,
+                                  interpret=interpret)
+    return fused_lanes_jit(buf, nb, rk_planes, rounds=rounds)
 
 
 class FusedTile:
@@ -110,7 +109,7 @@ def submit(cts: list, keys: list, *, interpret: bool | None = None,
         rk = round_key_planes(keys, buf.shape[0])
     h2d = buf.nbytes + nb.nbytes + rk.nbytes
     with span("repro.kernel.dispatch", h2d_bytes=h2d):
-        dig, plain = _fused_device(buf, nb, rk, rounds=rk.shape[0] - 1,
+        dig, plain = _fused_device(buf, nb, rk, rounds=rk.shape[1] - 1,
                                    pallas=pallas, interpret=interpret)
         dig.copy_to_host_async()
         plain.copy_to_host_async()

@@ -81,13 +81,6 @@ def words_from_bytes(buf):
     return bswap32(buf).reshape(lanes, -1, 16).transpose(2, 1, 0)
 
 
-def bytes_from_words(words):
-    """Inverse of ``words_from_bytes``: (16, maxb, lanes) big-endian
-    words -> (lanes, maxb*16) int32 whose native bytes are the stream."""
-    lanes = words.shape[-1]
-    return bswap32(words.transpose(2, 1, 0)).reshape(lanes, -1)
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _sha_device(buf, nb, *, interpret: bool):
     return sha256_lanes_pallas(words_from_bytes(buf), nb,

@@ -16,6 +16,7 @@ import pytest
 from repro.core.crypto import aes, convergent
 from repro.core.decode import BatchDecoder
 from repro.kernels.fused import fused_verify_decrypt
+from repro.kernels.fused.fusedp import KS_TILE
 
 RNG = np.random.default_rng(77)
 
@@ -33,11 +34,40 @@ def _batch(lens):
 
 # ------------------------------------------------ the fused pass itself
 
-@pytest.mark.parametrize("route", ["jit", "pallas"])
-def test_fused_boundary_lengths_match_oracles(route):
-    """digest == hashlib and plaintext == serial CTR for every padding
-    boundary, through both lowering routes of the fused kernel."""
-    cts, keys = _batch(BOUNDARY_LENS)
+CHUNK = 512 << 10
+# (lengths, key bytes, lanes that share the first lane's key)
+CASES = {
+    "boundary": (BOUNDARY_LENS, 32, ()),
+    "one_chunk": ([CHUNK], 32, ()),
+    "two_512k_chunks": ([CHUNK, CHUNK], 32, ()),
+    # a partial last AES block, lengths off 16 and 64, exactly 64 blocks
+    "mixed": ([64 * 64, 64 * 64 - 9, 1000, 4095, 33, 64 * 64 + 1], 32, ()),
+    "shared_key": ([4096, 4096, 700, 4096], 32, (1, 3)),
+    "aes128": ([4096, 1000, 55, 64 * 64], 16, ()),
+}
+
+
+def _keyed_batch(case):
+    lens, key_bytes, shared = CASES[case]
+    cts = [RNG.integers(0, 256, L, dtype=np.uint8).tobytes() for L in lens]
+    keys = [RNG.integers(0, 256, key_bytes, dtype=np.uint8).tobytes()
+            for _ in lens]
+    for i in shared:
+        keys[i] = keys[0]
+    return cts, keys
+
+
+@pytest.mark.parametrize("route, case", [
+    pytest.param(route, case,
+                 id=route if case == "boundary" else f"{route}-{case}")
+    for case in CASES for route in ("jit", "pallas")])
+def test_fused_boundary_lengths_match_oracles(route, case):
+    """digest == hashlib and plaintext == serial CTR, byte for byte,
+    through both lowering routes of the fused pass: every padding
+    boundary in one tile; one chunk; two 512 KiB chunks in one tile;
+    mixed lengths; a key in every lane and two lanes sharing one; both
+    AES key sizes."""
+    cts, keys = _keyed_batch(case)
     kw = ({"pallas": False} if route == "jit"
           else {"pallas": True, "interpret": True})
     digests, plains = fused_verify_decrypt(cts, keys, **kw)
@@ -45,6 +75,25 @@ def test_fused_boundary_lengths_match_oracles(route):
         assert d == hashlib.sha256(ct).digest(), (route, len(ct))
         assert p == aes.ctr_decrypt(ct, k), (route, len(ct))
     assert fused_verify_decrypt([], []) == ([], [])
+
+
+def test_fused_h2d_bytes_do_not_grow_with_blocks():
+    """Beside the message buffer, a tile sends the same bytes whatever
+    its chunks' lengths: one key schedule and one block count per lane.
+    Per-block or per-word key planes would grow with the block count."""
+    from repro.core.telemetry import COUNTERS, H2D_BYTES
+    from repro.kernels.sha256.ops import pack_messages
+
+    extra = []
+    for n in (4096, 64 << 10):
+        cts, keys = _batch([n, n])
+        buf, _ = pack_messages(cts)
+        before = COUNTERS.snapshot().get(H2D_BYTES, 0)
+        digests, _ = fused_verify_decrypt(cts, keys, pallas=False)
+        assert digests == [hashlib.sha256(ct).digest() for ct in cts]
+        extra.append(COUNTERS.snapshot()[H2D_BYTES] - before - buf.nbytes)
+    lanes, rounds = buf.shape[0], 14
+    assert extra[0] == extra[1] == lanes * 4 * (1 + (rounds + 1) * 16 * 8)
 
 
 @pytest.mark.parametrize("route", ["jit", "pallas"])
@@ -68,27 +117,38 @@ def test_fused_submit_then_result_equals_one_call(route):
     assert submit([], [], **kw).result() == ([], [])
 
 
-def test_fused_kernel_multi_lane_tile_multi_step():
-    """The Pallas kernel over a (2 lane tiles) x (2 block steps) grid:
+@pytest.mark.parametrize("ks_tile", [KS_TILE, 8])
+def test_fused_kernel_multi_lane_tile_multi_step(ks_tile):
+    """The Pallas kernels over a (2 lane tiles) x (2 block steps) walk:
     each lane tile restarts its digest state, carries it across steps,
-    and keys its keystream per lane, long and short lanes in both."""
-    from repro.kernels.fused.fusedp import STEP, fused_lanes_pallas
+    and XORs its lanes' keystream, long and short lanes in both. With a
+    keystream tile of 8 words the keystream grid takes two word steps,
+    and the lanes that end in the first skip the second."""
+    from repro.kernels.fused.fusedp import (
+        keystream_pallas,
+        native_keystream,
+        walk_pallas,
+    )
     from repro.kernels.fused.ops import round_key_planes
     from repro.kernels.sha256.ops import (
-        bytes_from_words,
         digests_to_bytes,
         pack_messages,
         words_from_bytes,
     )
-    cts, keys = _batch([1000, 0, 55, 64 * STEP - 9, 1, 200, 513, 64,
-                        7, 900, 119, 64 * STEP + 100, 2, 600, 56, 1013])
+    from repro.kernels.sha256.sha256p import STEP_BLOCKS
+    blocks = 64 * STEP_BLOCKS
+    cts, keys = _batch([1000, 0, 55, blocks - 9, 1, 200, 513, 64,
+                        7, 900, 119, blocks + 100, 2, 600, 56, 1013])
     buf, nb = pack_messages(cts)
-    assert buf.shape[0] == 16 and buf.shape[1] // 16 == 2 * STEP
+    maxb = buf.shape[1] // 16
+    assert buf.shape[0] == 16 and maxb == 2 * STEP_BLOCKS
     rk = round_key_planes(keys, buf.shape[0])
-    dig, plain = fused_lanes_pallas(words_from_bytes(buf), nb, rk,
-                                    rounds=rk.shape[0] - 1, interpret=True,
-                                    block=8)
-    plain = np.asarray(bytes_from_words(plain)).view(np.uint8)
+    ks = keystream_pallas(nb, rk, maxb=maxb, rounds=14, interpret=True,
+                          tile=ks_tile)
+    assert ks.shape == (16, 128, maxb // 8)
+    dig, plain = walk_pallas(words_from_bytes(buf), nb, buf,
+                             native_keystream(ks), interpret=True, block=8)
+    plain = np.asarray(plain).view(np.uint8)
     assert digests_to_bytes(dig, len(cts)) == [
         hashlib.sha256(ct).digest() for ct in cts]
     for i, (ct, k) in enumerate(zip(cts, keys)):

@@ -16,7 +16,11 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core.layout import CHUNK_SIZE
 from repro.kernels.aes.bitslice_pallas import encrypt_planes_pallas
-from repro.kernels.fused.fusedp import fused_lanes_pallas
+from repro.kernels.fused.fusedp import (
+    fused_lanes_pallas,
+    keystream_pallas,
+    walk_pallas,
+)
 from repro.kernels.sha256.ops import _bucket_blocks, _bucket_lanes
 from repro.kernels.sha256.sha256p import LANE_BLOCK, sha256_lanes_pallas
 
@@ -55,24 +59,48 @@ def _compiled_text(fn, sharding, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+# a 1 MiB tile of 4 KiB chunks: 256 lanes, two lane tiles of the walk
+MULTI_MAXB = _bucket_blocks((4096 + 9 + 63) // 64)
+MULTI_LANES = _bucket_lanes((1 << 20) // 4096)
+TILES = {"512k_chunk": (MAXB, LANES), "multi_lane": (MULTI_MAXB, MULTI_LANES)}
+
+
+def _fused_text(one_chip, maxb: int, lanes: int) -> str:
+    return _compiled_text(
+        lambda b, nb, rk: fused_lanes_pallas(b, nb, rk, rounds=ROUNDS),
+        one_chip, (lanes, 16 * maxb), (1, lanes),
+        (lanes, ROUNDS + 1, 16, 8))
+
+
 def test_fused_kernel_compiles_at_512k_chunk_tile(one_chip):
-    text = _compiled_text(
-        lambda w, nb, rk: fused_lanes_pallas(w, nb, rk, rounds=ROUNDS),
-        one_chip, (16, MAXB, LANES), (1, LANES),
-        (ROUNDS + 1, 8, 16, LANES))
+    text = _fused_text(one_chip, MAXB, LANES)
     assert "tpu_custom_call" in text
+    assert "aes_bitsliced" in text and "fused_verify_decrypt" in text
 
 
 def test_fused_kernel_compiles_at_multi_lane_tile(one_chip):
-    # a 1 MiB tile of 4 KiB chunks: 256 lanes, two lane tiles of the grid
-    maxb = _bucket_blocks((4096 + 9 + 63) // 64)
-    lanes = _bucket_lanes((1 << 20) // 4096)
-    assert lanes > LANE_BLOCK
-    text = _compiled_text(
-        lambda w, nb, rk: fused_lanes_pallas(w, nb, rk, rounds=ROUNDS),
-        one_chip, (16, maxb, lanes), (1, lanes),
-        (ROUNDS + 1, 8, 16, lanes))
+    assert MULTI_LANES > LANE_BLOCK
+    text = _fused_text(one_chip, MULTI_MAXB, MULTI_LANES)
     assert "tpu_custom_call" in text
+    assert "aes_bitsliced" in text and "fused_verify_decrypt" in text
+
+
+@pytest.mark.parametrize("tile", TILES)
+def test_keystream_kernel_compiles(one_chip, tile):
+    maxb, lanes = TILES[tile]
+    text = _compiled_text(
+        lambda nb, rk: keystream_pallas(nb, rk, maxb=maxb, rounds=ROUNDS),
+        one_chip, (1, lanes), (lanes, ROUNDS + 1, 16, 8))
+    assert "tpu_custom_call" in text and "aes_bitsliced" in text
+
+
+@pytest.mark.parametrize("tile", TILES)
+def test_walk_kernel_compiles(one_chip, tile):
+    maxb, lanes = TILES[tile]
+    text = _compiled_text(
+        walk_pallas, one_chip, (16, maxb, lanes), (1, lanes),
+        (lanes, 16 * maxb), (lanes, 16 * maxb))
+    assert "tpu_custom_call" in text and "fused_verify_decrypt" in text
 
 
 def test_sha256_kernel_compiles_at_512k_chunk_tile(one_chip):
